@@ -214,18 +214,24 @@ def implicit_case_child(dims: tuple[int, ...], n: int,
 
 def _measure_implicit(dims: tuple[int, ...], n: int, backend: str,
                       csv=print) -> dict:
-    """Run one implicit case in a subprocess and parse its JSON row."""
-    repo = Path(__file__).resolve().parents[1]
-    cmd = [sys.executable, "-m", "benchmarks.mapping_scale",
-           "--implicit-case", "x".join(map(str, dims)), str(n),
-           "--backend", backend]
-    import os
-    env = dict(os.environ)
-    env["PYTHONPATH"] = str(repo / "src") + (
-        os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
-    out = subprocess.run(cmd, cwd=repo, env=env, capture_output=True,
-                         text=True, check=True)
-    row = json.loads(out.stdout.strip().splitlines()[-1])
+    """Run one implicit case and report its row: in a subprocess (so
+    peak RSS is per case), except on the jax backend, which runs it in
+    this process — a chip serves one process, and the caller may already
+    hold it.  In-process, ``peak_rss_bytes`` covers the whole run."""
+    if backend == "jax":
+        row = implicit_case_child(dims, n, backend=backend)
+    else:
+        repo = Path(__file__).resolve().parents[1]
+        cmd = [sys.executable, "-m", "benchmarks.mapping_scale",
+               "--implicit-case", "x".join(map(str, dims)), str(n),
+               "--backend", backend]
+        import os
+        env = dict(os.environ)
+        env["PYTHONPATH"] = str(repo / "src") + (
+            os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
+        out = subprocess.run(cmd, cwd=repo, env=env, capture_output=True,
+                             text=True, check=True)
+        row = json.loads(out.stdout.strip().splitlines()[-1])
     csv(f"mapping_scale,{row['case']},implicit,{row['warm_s']*1e3:.0f},"
         f"ms_place_time,cold={row['cold_s']:.2f}s,"
         f"replace={row['replace_s']*1e3:.0f}ms,"

@@ -19,18 +19,24 @@ Selection (first match wins):
 * default: ``numpy``.
 
 Dtype policy: the NumPy kernels are pinned to float64 (the committed
-quality/parity baseline).  The jax backend computes in ``float64`` by
-default — with in-tree workloads every guest weight and route distance is
-an exactly-representable integer, so the jitted kernels reproduce the NumPy
-placements *bit-for-bit* — and can be switched to ``float32``
-(``REPRO_JAX_DTYPE=float32`` or ``set_backend("jax", dtype="float32")``)
-when throughput on accelerators matters more than cross-backend parity.
-``jax.config`` handling lives here, inside the backend: float64 kernel
-calls run under a *scoped* ``jax.experimental.enable_x64`` context
-(:meth:`JaxBackend.scope`), so neither call sites nor the float32
-accelerator stack ever see mutated global JAX state.  Placements are
-integer node-id arrays on every backend (asserted in
-``tests/test_backend_diff.py``), never floats.
+quality/parity baseline).  The jax backend's dtype is chosen by the
+platform.  Off TPU it computes in ``float64`` — with in-tree workloads
+every guest weight and route distance is an exactly-representable
+integer, so the jitted kernels reproduce the NumPy placements
+*bit-for-bit*.  On TPU it computes in ``float32``: the chip has no native
+float64 and its compiler refuses every Pallas kernel of the hot path
+inside the x64 scope, so placements there are held to quality (hop-bytes)
+rather than identity.  An explicit ``dtype=`` (``set_backend("jax",
+dtype=...)``, ``use(...)``) still wins.  ``jax.config`` handling lives
+here, inside the backend: float64 kernel calls run under a *scoped*
+``jax.enable_x64`` context (:meth:`JaxBackend.scope`), so neither call
+sites nor the float32 accelerator stack ever see mutated global JAX
+state.  Placements are integer node-id arrays on every backend (asserted
+in ``tests/test_backend_diff.py``), never floats.
+
+The first construction of the jax backend also fixes JAX's persistent
+compilation cache: ``JAX_COMPILATION_CACHE_DIR`` when it is set (JAX reads
+it itself), else ``.jax_cache/`` at the repository root.
 
 A NumPy-only install never imports JAX: requesting the jax backend without
 the optional dependency raises :class:`BackendUnavailableError` and
@@ -41,6 +47,7 @@ from __future__ import annotations
 import contextlib
 import os
 from collections import OrderedDict
+from pathlib import Path
 from typing import Iterator, Optional
 
 import numpy as np
@@ -65,28 +72,30 @@ class JaxBackend:
     """JAX backend: jitted kernels + device-resident distance matrices.
 
     ``dtype`` selects the compute precision of the jitted kernels
-    (placement ids stay integers regardless).  ``float64`` (default)
-    runs every kernel call and device transfer inside a *scoped*
-    ``jax.experimental.enable_x64`` context (:meth:`scope`) — the
-    process-wide ``jax_enable_x64`` flag is never touched, so the
-    accelerator stack's float32 world is unaffected by placement calls
-    and vice versa (scoped config participates in the jit cache key).
+    (placement ids stay integers regardless); :func:`get_backend` lets
+    the platform choose (:func:`platform_dtype`).  ``float64`` runs every
+    kernel call and device transfer inside a *scoped* ``jax.enable_x64``
+    context (:meth:`scope`) — the process-wide ``jax_enable_x64`` flag is
+    never touched, so the accelerator stack's float32 world is unaffected
+    by placement calls and vice versa (scoped config participates in the
+    jit cache key).
     """
 
     name = "jax"
     is_jax = True
 
-    def __init__(self, dtype: str = "float64", max_cached_devices: int = 8,
+    def __init__(self, dtype: str, max_cached_devices: int = 8,
                  devices: Optional[int] = None):
-        if dtype not in ("float32", "float64"):
-            raise ValueError(f"jax backend dtype must be float32|float64, "
-                             f"got {dtype!r}")
         try:
-            import jax  # noqa: F401  (deferred: numpy-only installs)
+            import jax
         except ImportError as e:  # pragma: no cover - exercised on bare envs
             raise BackendUnavailableError(
                 "the 'jax' placement backend needs the optional jax "
                 "dependency: pip install repro-tofa[jax]") from e
+        _init_compile_cache(jax)
+        if dtype not in ("float32", "float64"):
+            raise ValueError(f"jax backend dtype must be float32|float64, "
+                             f"got {dtype!r}")
         self.dtype = dtype
         # cap on the devices the sharded candidate-stack dispatch may
         # use; 0 = all local devices.  REPRO_JAX_DEVICES=1 pins the
@@ -104,9 +113,10 @@ class JaxBackend:
         # cache: one (topology, state epoch) == one matrix object == one
         # transfer.  The counters make that contract testable
         # (tests/test_state.py asserts zero new transfers across a warm
-        # state-churn sequence).
+        # state-churn sequence); ``numpy_fallbacks`` counts the calls the
+        # dispatch layer (``mapping._jax_kernels``) turned away to NumPy.
         self.stats = {"transfers": 0, "transfer_hits": 0,
-                      "sharded_dispatches": 0}
+                      "sharded_dispatches": 0, "numpy_fallbacks": 0}
 
     def __repr__(self) -> str:  # pragma: no cover - debugging aid
         return (f"<backend {self.name} dtype={self.dtype} "
@@ -128,8 +138,8 @@ class JaxBackend:
         """Context the jitted kernels run under: scoped x64 for the
         float64 dtype policy, a no-op for float32."""
         if self.dtype == "float64":
-            from jax.experimental import enable_x64
-            return enable_x64()
+            import jax
+            return jax.enable_x64(True)
         return contextlib.nullcontext()
 
     def device_matrix(self, arr: np.ndarray):
@@ -168,6 +178,32 @@ class JaxBackend:
         self._device.clear()
 
 
+_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def _init_compile_cache(jax) -> None:
+    """Point JAX's persistent compilation cache at ``.jax_cache/`` in the
+    repository root, unless ``JAX_COMPILATION_CACHE_DIR`` (or code) has
+    already chosen a directory.  The path is fixed because it is part of
+    the cache key: a directory that moves never hits.  On TPU every
+    program is cached, however quick its compile: a service compiles
+    dozens of small refine programs, each well under JAX's default
+    one-second threshold."""
+    if not (os.environ.get("JAX_COMPILATION_CACHE_DIR")
+            or jax.config.jax_compilation_cache_dir):
+        jax.config.update("jax_compilation_cache_dir", str(_CACHE_DIR))
+    if jax.default_backend() == "tpu":
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+
+
+def platform_dtype() -> str:
+    """The jax backend's compute dtype on this platform: ``float32`` on
+    TPU (no Pallas kernel of the hot path compiles under x64 there),
+    ``float64`` elsewhere (bit-identity with the NumPy kernels)."""
+    import jax
+    return "float32" if jax.default_backend() == "tpu" else "float64"
+
+
 def has_jax() -> bool:
     """True when the optional jax dependency is importable."""
     try:
@@ -191,7 +227,7 @@ def _resolve_devices(devices: Optional[int]) -> int:
 def _jax_backend(dtype: Optional[str] = None,
                  devices: Optional[int] = None) -> JaxBackend:
     global _JAX
-    want = dtype or os.environ.get("REPRO_JAX_DTYPE", "float64")
+    want = dtype or platform_dtype()
     want_dev = _resolve_devices(devices)
     if _JAX is None or _JAX.dtype != want or _JAX.devices != want_dev:
         _JAX = JaxBackend(dtype=want, devices=want_dev)
@@ -214,6 +250,16 @@ _ACTIVE = get_backend(os.environ.get("REPRO_BACKEND", "numpy"))
 def active():
     """The backend the mapping kernels currently dispatch to."""
     return _ACTIVE
+
+
+def on_accelerator() -> bool:
+    """True when the active backend is jax on an accelerator.  Such a
+    process holds its chip, and a chip serves one process at a time: a
+    worker process started now could not reach it."""
+    if not _ACTIVE.is_jax:
+        return False
+    import jax
+    return jax.default_backend() != "cpu"
 
 
 def set_backend(name: str, dtype: Optional[str] = None,

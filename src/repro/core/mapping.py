@@ -46,7 +46,9 @@ jit-compiled kernels of :mod:`repro.core.mapping_jax` — decision-identical
 at float64 (bit-identical placements for the integer-weighted in-tree
 workloads), with all candidate refinements of one mapping call batched
 into a single device dispatch.  Asymmetric guest matrices (outside the
-CommGraph convention) silently fall back to the NumPy kernels.  Inside
+CommGraph convention) and lazy distances without an implicit spec fall
+back to the NumPy kernels, counted in the backend's
+``stats["numpy_fallbacks"]``.  Inside
 ``use_reference_impl`` the retained scalar loops always run, regardless
 of backend — they are the fixed baseline.
 """
@@ -66,14 +68,16 @@ def _jax_kernels(G_w: np.ndarray | None = None, D=None):
     check for guest-dependent kernels; ``D`` adds the lazy-distance
     check — a lazy adapter is served only when the backend can compute
     its entries in-kernel (implicit torus), otherwise the NumPy kernels
-    run against the adapter's ``__getitem__``."""
+    run against the adapter's ``__getitem__``.  Each such turn-away is
+    counted in the jax backend's ``stats["numpy_fallbacks"]``."""
     be = _backend.active()
     if not getattr(be, "is_jax", False):
         return None
     from . import mapping_jax
-    if G_w is not None and not mapping_jax.guest_supported(G_w):
-        return None
-    if D is not None and is_lazy(D) and not mapping_jax.lazy_supported(D):
+    if ((G_w is not None and not mapping_jax.guest_supported(G_w))
+            or (D is not None and is_lazy(D)
+                and not mapping_jax.lazy_supported(D))):
+        be.stats["numpy_fallbacks"] += 1
         return None
     return mapping_jax
 
@@ -328,7 +332,8 @@ def snake_order(nodes: np.ndarray, coords: np.ndarray) -> np.ndarray:
 # node subset selection (|V_H| > |V_G|)
 # --------------------------------------------------------------------------
 
-def select_nodes(D: np.ndarray, count: int, seed: int | None = None) -> np.ndarray:
+def select_nodes(D: np.ndarray, count: int, seed: int | None = None,
+                 allowed: np.ndarray | None = None) -> np.ndarray:
     """Greedily grow a compact low-weight subset of ``count`` nodes.
 
     ``D`` is the (fault-aware) pairwise weight matrix of the full topology.
@@ -337,16 +342,24 @@ def select_nodes(D: np.ndarray, count: int, seed: int | None = None) -> np.ndarr
     minimum total weight to the chosen set.  The Eq. 1 fault penalty (100x)
     makes faulty nodes effectively unselectable unless unavoidable.
 
+    ``allowed`` (lazy ``D`` only) is an (N,) bool mask of the nodes the
+    subset may take.  A dense caller restricts ``D`` itself
+    (``D[np.ix_(ids, ids)]``).
+
     The frontier cost vector is maintained in place across steps — chosen
     entries are pinned to +inf, so each step is one argmin + one row add,
     with no per-step masked copy of the full N-node array.
     """
     lazy = is_lazy(D)
+    if allowed is not None and not lazy:
+        raise ValueError("allowed= is for lazy D; restrict a dense D "
+                         "with D[np.ix_(ids, ids)]")
     jx = None if lazy else _jax_kernels()
     if jx is not None:
         return jx.select_nodes(D, count, seed=seed)
     n = D.shape[0]
-    count = min(count, n)
+    ids = np.arange(n) if allowed is None else np.flatnonzero(allowed)
+    count = min(count, len(ids))
     if seed is None:
         if lazy:
             # blocked row generation keeps peak memory O(block * n); the
@@ -354,14 +367,15 @@ def select_nodes(D: np.ndarray, count: int, seed: int | None = None) -> np.ndarr
             # path is the small-n / direct-call fallback
             best, seed = np.inf, 0
             step = max(1, 8_000_000 // max(n, 1))
-            rows_idx = np.arange(n)
-            for s in range(0, n, step):
-                rows = D[rows_idx[s:s + step]]
+            for s in range(0, len(ids), step):
+                rows = D[ids[s:s + step]]
+                if allowed is not None:
+                    rows = rows[:, allowed]
                 part = np.partition(rows, count - 1, axis=1)[:, :count]
                 sums = part.sum(axis=1)
                 k = int(np.argmin(sums))
                 if sums[k] < best:
-                    best, seed = float(sums[k]), s + k
+                    best, seed = float(sums[k]), int(ids[s + k])
         else:
             # cost of the best `count`-node ball centred at each node
             part = np.partition(D, count - 1, axis=1)[:, :count]
@@ -369,6 +383,8 @@ def select_nodes(D: np.ndarray, count: int, seed: int | None = None) -> np.ndarr
     chosen = np.zeros(n, dtype=bool)
     chosen[seed] = True
     cost = D[seed].astype(np.float64, copy=True)
+    if allowed is not None:
+        cost[~allowed] = np.inf
     cost[seed] = np.inf
     for _ in range(count - 1):
         nxt = int(np.argmin(cost))

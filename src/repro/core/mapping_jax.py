@@ -274,7 +274,10 @@ def _refine_one(p0, idx, val, G_dense, Ds, n_valid, *, movers: int,
         # a (contiguous) row read instead
         idx_i, val_i = idx[i], val[i].astype(fdt)
         Mrow_i = M[i]
-        a = val_i @ M[idx_i, :]                          # M @ G[i]
+        # full-precision matvec: TPU's default f32 dot is one bf16 pass,
+        # which would round guest byte counts of ~1e7
+        a = jnp.dot(val_i, M[idx_i, :],
+                    precision=lax.Precision.HIGHEST)     # M @ G[i]
         b = (val.astype(fdt)
              * Mrow_i[idx]).sum(-1)                      # G @ M[i]
         Ci = jnp.zeros(n, fdt).at[idx_i].add(val_i * Mrow_i[idx_i])
@@ -421,15 +424,14 @@ def _refine_jit_sharded(movers: int, total_passes: int, dense: bool,
     deadlock its rendezvous under concurrent dispatches and mis-replicate
     on sub-meshes.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     fn = functools.partial(_refine_one, movers=movers,
                            total_passes=total_passes, dense=dense,
                            dims=dims, scale=scale, sortless=True)
     batched = jax.vmap(fn, in_axes=(0, None, None, None, None, None))
-    sharded = shard_map(batched, mesh=_mesh(n_dev),
-                        in_specs=(P("dev"), P(), P(), P(), P(), P()),
-                        out_specs=P("dev"), check_rep=False)
+    sharded = jax.shard_map(batched, mesh=_mesh(n_dev),
+                            in_specs=(P("dev"), P(), P(), P(), P(), P()),
+                            out_specs=P("dev"), check_vma=False)
     return jax.jit(sharded)
 
 
@@ -484,30 +486,43 @@ def refine_many(G_w: np.ndarray, D: np.ndarray, placements: np.ndarray,
     duplicates are free of side effects and sliced off).
     """
     be = _be()
-    P, n, n_pad = _pad_placements(np.atleast_2d(placements))
+    B, n = np.atleast_2d(placements).shape
     with be.scope():
-        idx, val, G_dense, dense = _guest_device(G_w, n_pad, be)
-        Ds, dims, scale = _device_distances(D, be)
-        movers_eff = min(movers, n_pad)
-        B = P.shape[0]
-        n_dev = min(int(getattr(be, "device_count", 1)), B)
+        run, args, n_dev = refine_program(G_w, D, placements, max_passes,
+                                          movers, extra_passes)
         if n_dev > 1:
-            pad_b = (-B) % n_dev
-            if pad_b:
-                P = np.pad(P, ((0, pad_b), (0, 0)), mode="edge")
-            run = _refine_jit_sharded(movers_eff, max_passes + extra_passes,
-                                      dense, dims, scale, n_dev)
-            be.stats["sharded_dispatches"] = (
-                be.stats.get("sharded_dispatches", 0) + 1)
-            args = _shard_args(n_dev, P, idx, val, G_dense, Ds,
-                               jnp.int32(n))
-        else:
-            run = _refine_jit(movers_eff, max_passes + extra_passes, dense,
-                              dims, scale)
-            args = (jnp.asarray(P), idx, val, G_dense, Ds, jnp.int32(n))
+            be.stats["sharded_dispatches"] += 1
         out = run(*args)
     out = np.asarray(out)[:B, :n].astype(np.int64)
     return out if np.asarray(placements).ndim == 2 else out[0]
+
+
+def refine_program(G_w: np.ndarray, D, placements: np.ndarray,
+                   max_passes: int = 3, movers: int = 64,
+                   extra_passes: int = 13):
+    """``(jitted refine, operands, n_dev)``: the dispatch
+    :func:`refine_many` makes for these arguments, on ``n_dev`` devices.
+    ``run.lower(*operands)`` shows the program it compiles.  Call inside
+    the active backend's :meth:`~repro.core.backend.JaxBackend.scope`."""
+    be = _be()
+    P, n, n_pad = _pad_placements(np.atleast_2d(placements))
+    idx, val, G_dense, dense = _guest_device(G_w, n_pad, be)
+    Ds, dims, scale = _device_distances(D, be)
+    movers_eff = min(movers, n_pad)
+    B = P.shape[0]
+    n_dev = min(int(be.device_count), B)
+    if n_dev > 1:
+        pad_b = (-B) % n_dev
+        if pad_b:
+            P = np.pad(P, ((0, pad_b), (0, 0)), mode="edge")
+        run = _refine_jit_sharded(movers_eff, max_passes + extra_passes,
+                                  dense, dims, scale, n_dev)
+        args = _shard_args(n_dev, P, idx, val, G_dense, Ds, jnp.int32(n))
+    else:
+        run = _refine_jit(movers_eff, max_passes + extra_passes, dense,
+                          dims, scale)
+        args = (jnp.asarray(P), idx, val, G_dense, Ds, jnp.int32(n))
+    return run, args, n_dev
 
 
 def _guest_device(G_w: np.ndarray, n_pad: int, be):

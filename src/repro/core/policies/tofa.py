@@ -127,14 +127,12 @@ class TofaPolicy:
         # tie a directly-faulty node with healthy nodes whose routes merely
         # *pass through* faults, and lose that tie.  Faulty nodes are used
         # only when the job cannot fit on healthy ones (the paper's
-        # tolerance trade-off).
+        # tolerance trade-off).  Either way selection stays inside the
+        # allocatable set: W prices busy nodes as routers, not as hosts.
         healthy = np.flatnonzero(p_f == 0)
-        if len(healthy) >= n:
-            sub = mapping.select_nodes(W[np.ix_(healthy, healthy)], n)
-            nodes = healthy[sub]
-        else:
-            nodes = mapping.select_nodes(W, n)
-        return False, [nodes]
+        pool = healthy if len(healthy) >= n else ctx.available
+        sub = mapping.select_nodes(W[np.ix_(pool, pool)], n)
+        return False, [pool[sub]]
 
 
 @register_policy("tofa-ml")
@@ -208,12 +206,17 @@ class TofaMultilevelPolicy(TofaPolicy):
         if hasattr(topo, "hierarchy_groups"):
             groups = topo.hierarchy_groups(max(64, N // 256))
             healthy = p_f == 0
-            hmask = healthy if healthy.sum() >= n else None
+            enough = healthy.sum() >= n
+            if enough:
+                hmask = healthy
+            else:                            # allocatable, faulty or not
+                hmask = np.zeros(N, dtype=bool)
+                hmask[ctx.available] = True
             ball = multilevel.hierarchical_select(W, groups, n, healthy=hmask)
             if len(ball) >= n:
                 candidates.append(ball)
             faulty = np.flatnonzero(p_f > 0)
-            if faulty.size and hmask is not None:
+            if faulty.size and enough:
                 # a second ball grown from the rack farthest from any
                 # fault — the lazy analogue of the dense path's
                 # far-seeded select_nodes candidates.  Rep-to-fault
@@ -232,6 +235,9 @@ class TofaMultilevelPolicy(TofaPolicy):
                 if len(ball2) >= n:
                     candidates.append(ball2)
         if not candidates:
-            # last resort: lazy-aware frontier growth (blocked seed scan)
-            candidates.append(mapping.select_nodes(W, n))
+            # last resort: lazy-aware frontier growth (blocked seed scan),
+            # confined to the allocatable nodes like every other candidate
+            allowed = np.zeros(N, dtype=bool)
+            allowed[ctx.available] = True
+            candidates.append(mapping.select_nodes(W, n, allowed=allowed))
         return S is not None, candidates

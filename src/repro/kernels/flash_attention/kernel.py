@@ -107,12 +107,9 @@ def flash_attention_tpu(
         _flash_kernel, causal=causal, sk=Sk, sq=Sq, block_q=block_q,
         block_k=block_k, num_kv=nk)
 
-    try:
-        cparams = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"))
-    except Exception:  # pragma: no cover - older pallas naming
-        cparams = None
+    cparams = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "parallel",
+                             "arbitrary"))
 
     out = pl.pallas_call(
         kernel,

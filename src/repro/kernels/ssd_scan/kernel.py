@@ -83,11 +83,8 @@ def ssd_scan_tpu(xdt, dA, B, C, chunk: int = 128, interpret: bool = False):
     nc = S // chunk
 
     kernel = functools.partial(_ssd_kernel, num_chunks=nc)
-    try:
-        cparams = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
-    except Exception:  # pragma: no cover
-        cparams = None
+    cparams = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))
 
     y, st = pl.pallas_call(
         kernel,

@@ -1,28 +1,11 @@
-"""swap_gain — jit'd public wrapper with backend dispatch."""
+"""swap_select — jit'd public wrapper with backend dispatch."""
 from __future__ import annotations
 
 import functools
 
 import jax
 
-from repro.kernels.swap_gain.ref import swap_gain_ref, swap_select_ref
-
-
-@functools.partial(jax.jit, static_argnames=("impl",))
-def swap_gain(M, G, contrib, i, *, impl: str = "auto"):
-    """Dense gains row of the pairwise-swap refiner for mover ``i``.
-
-    ``impl="auto"`` runs the Pallas kernel on TPU and the jitted-jnp
-    reference everywhere else (the fallback contract of the mapping
-    backend's dense path).
-    """
-    if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" else "ref"
-    if impl in ("pallas", "pallas_interpret"):
-        from repro.kernels.swap_gain.kernel import swap_gain_tpu
-        return swap_gain_tpu(M, G, contrib, i,
-                             interpret=(impl == "pallas_interpret"))
-    return swap_gain_ref(M, G, contrib, i)
+from repro.kernels.swap_gain.ref import swap_select_ref
 
 
 @functools.partial(jax.jit, static_argnames=("impl",))
@@ -33,11 +16,12 @@ def swap_select(M, G, contrib, i, n_valid, *, impl: str = "auto"):
     Returns ``(gain, j)`` scalars; ``j == i`` encodes a rejected mover
     (the identity-swap convention of ``mapping_jax._refine_one``), so the
     refine loop applies the returned swap unconditionally and never
-    materialises a gains row.  Decision-identical to composing
-    :func:`swap_gain` with the loop's own mask/argmax/threshold — the
-    Pallas kernel and the jitted reference share the arithmetic and the
-    first-occurrence tie-break (differentially tested in
-    ``tests/test_kernels.py``).
+    materialises a gains row.  ``impl="auto"`` runs the Pallas kernel on
+    TPU and the jitted reference everywhere else.  Decision-identical to
+    composing :func:`.ref.swap_gain_ref` with the loop's own
+    mask/argmax/threshold — the Pallas kernel and the jitted reference
+    share the arithmetic and the first-occurrence tie-break
+    (differentially tested in ``tests/test_kernels.py``).
     """
     if impl == "auto":
         impl = "pallas" if jax.default_backend() == "tpu" else "ref"

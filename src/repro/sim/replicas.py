@@ -52,6 +52,7 @@ from typing import Callable, Optional, Sequence
 
 import numpy as np
 
+from repro.core.backend import on_accelerator
 from repro.sim.scenarios import SCENARIOS, run_preset
 
 # wall-clock fields: nondeterministic across runs, excluded from the
@@ -403,7 +404,9 @@ def run_replicas(
     ``base_seed + arange(n_replicas)``.  ``executor`` is ``"serial"``,
     ``"process"`` (seed-parallel worker pool, ``max_workers`` processes)
     or ``"auto"`` (process pool when it can help: > 1 CPU and enough
-    replicas to amortise worker startup).  ``max_workers=None`` or ``0``
+    replicas to amortise worker startup).  While the jax backend runs on
+    an accelerator, ``"auto"`` stays serial and ``"process"`` raises
+    ``RuntimeError`` (one process per chip).  ``max_workers=None`` or ``0``
     auto-detects ``os.cpu_count()``.  ``vectorize`` enables the
     bit-identical closed-form paper-mode path for ``paper-fig4-5``
     (``"auto"``/``"always"``/``"never"``).
@@ -443,6 +446,14 @@ def run_replicas(
                                      **preset_kw)))
         return ReplicaSet(name, fast, seeds, policies, collect.result())
 
+    if on_accelerator():
+        if executor == "process":
+            raise RuntimeError(
+                "executor='process' is unavailable while the jax backend "
+                "runs on an accelerator: this process holds the chip and "
+                "worker processes could not reach it; use 'serial' or "
+                "'auto'")
+        executor = "serial"
     workers = max_workers or (os.cpu_count() or 1)
     pooled = (executor == "process"
               or (executor == "auto" and workers > 1 and len(seeds) >= 8))

@@ -78,9 +78,11 @@ def test_refine_identical(host_name, topo, faulty):
     rng = np.random.default_rng(1)
     P = np.stack([rng.permutation(topo.n_nodes)[:40] for _ in range(3)])
     ref = mapping.refine_batch(wl.comm.G_v, D, P)
-    with backend.use("jax"):
+    with backend.use("jax") as be:
+        before = be.stats["numpy_fallbacks"]
         out = mapping.refine_batch(wl.comm.G_v, D, P)
         single = mapping._pairwise_refine(wl.comm.G_v, D, P[0])
+        assert be.stats["numpy_fallbacks"] == before    # served by jax
     assert np.array_equal(out, ref), f"{host_name} faulty={faulty}"
     assert np.array_equal(single, ref[0])
 
@@ -110,9 +112,11 @@ def test_fattree_lazy_refine_identical(health):
     P = np.stack([rng.permutation(topo.n_nodes)[:40] for _ in range(3)])
     ref = mapping.refine_batch(wl.comm.G_v, Dl, P)
     hb_ref = mapping.hop_bytes_batch(wl.comm.G_v, Dl, ref)
-    with backend.use("jax"):
+    with backend.use("jax") as be:
+        before = be.stats["numpy_fallbacks"]
         out = mapping.refine_batch(wl.comm.G_v, Dl, P)
         hb = mapping.hop_bytes_batch(wl.comm.G_v, Dl, out)
+        assert be.stats["numpy_fallbacks"] == before    # served by jax
     assert np.array_equal(out, ref), health
     np.testing.assert_allclose(hb, hb_ref, rtol=RTOL)
 
@@ -151,14 +155,46 @@ def test_policy_placements_identical(host_name, topo, faulty, policy):
     req = _request(topo, 24, faulty)
     ref = PlacementEngine().place(req, policy=policy,
                                   rng=np.random.default_rng(0))
-    with backend.use("jax"):
+    with backend.use("jax") as be:
+        before = be.stats["numpy_fallbacks"]
         out = PlacementEngine().place(req, policy=policy,
                                       rng=np.random.default_rng(0))
+        assert be.stats["numpy_fallbacks"] == before    # served by jax
     assert np.array_equal(out.placement, ref.placement), \
         f"{host_name} faulty={faulty} {policy}"
     assert out.placement.dtype.kind == "i"          # integer-exact
     assert ref.placement.dtype.kind == "i"
     np.testing.assert_allclose(out.hop_bytes, ref.hop_bytes, rtol=RTOL)
+
+
+@pytest.mark.parametrize("case", ["asymmetric-guest", "faulty-lazy-torus"])
+def test_numpy_fallbacks_counted(case):
+    """Calls the jitted kernels cannot serve run the NumPy kernels, and
+    each one is counted: a guest outside the symmetric CommGraph
+    convention, and a faulty torus above the lazy threshold (its lazy
+    distance has no implicit spec)."""
+    from repro.core import mapping_jax
+
+    topo = TorusTopology((4, 4, 4))
+    with backend.use("jax") as be:
+        before = be.stats["numpy_fallbacks"]
+        if case == "asymmetric-guest":
+            G = np.triu(npb_dt_like(24).comm.G_v)
+            P = np.stack([np.random.default_rng(s).permutation(64)[:24]
+                          for s in range(2)])
+            out = mapping.hop_bytes_batch(G, topo.hop_matrix(), P)
+            with backend.use("numpy"):
+                ref = mapping.hop_bytes_batch(G, topo.hop_matrix(), P)
+            np.testing.assert_array_equal(out, ref)
+        else:
+            req = _request(topo, 24, faulty=True)
+            engine = PlacementEngine(lazy_threshold=32)
+            assert not mapping_jax.lazy_supported(
+                engine._weights_for(topo, req, req.route_p_f()))
+            plan = engine.place(req, policy="tofa",
+                                rng=np.random.default_rng(0))
+            assert len(set(plan.placement.tolist())) == 24
+        assert be.stats["numpy_fallbacks"] > before
 
 
 def test_fractional_weight_guest_quality():
@@ -280,3 +316,34 @@ def test_reference_impl_wins_over_jax_backend():
             assert mapping.greedy_placement is \
                 mapping.greedy_placement_reference
     assert np.array_equal(out, ref)
+
+
+def test_compile_cache_dir(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR, when set, is left to JAX; otherwise the
+    cache goes to ``.jax_cache/`` at the repository root — a fixed path,
+    since the directory is part of the cache key.  Only on TPU is every
+    compile cached, however quick."""
+    import types
+    from pathlib import Path
+
+    class Config:
+        jax_compilation_cache_dir = None
+
+        def update(self, name, value):
+            setattr(self, name, value)
+
+    platform = ["cpu"]
+    fake = types.SimpleNamespace(config=Config(),
+                                 default_backend=lambda: platform[0])
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+    backend._init_compile_cache(fake)
+    assert fake.config.jax_compilation_cache_dir is None
+    assert not hasattr(fake.config,
+                       "jax_persistent_cache_min_compile_time_secs")
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    backend._init_compile_cache(fake)
+    root = Path(__file__).resolve().parents[1]
+    assert fake.config.jax_compilation_cache_dir == str(root / ".jax_cache")
+    platform[0] = "tpu"
+    backend._init_compile_cache(fake)
+    assert fake.config.jax_persistent_cache_min_compile_time_secs == 0

@@ -383,7 +383,10 @@ def test_fixed_placement_rejects_failure_process():
 
 # ------------------------------------------------------ scenario gates
 def test_tofa_beats_linear_in_saturated_queue():
-    out = run_preset("saturated-queue", fast=True, seed=0)
+    """At the preset's own size (8x8x8, 48 jobs).  The fast shrink (4x4x4
+    with a quarter of the nodes flaky, jobs of up to 18 ranks) leaves
+    exclusive allocation no room to steer around faults."""
+    out = run_preset("saturated-queue", fast=False, seed=0)
     assert (out["policies"]["tofa"]["mean_completion"]
             < out["policies"]["linear"]["mean_completion"])
 

@@ -239,3 +239,45 @@ def test_replace_honours_refreshed_availability(engine, torus):
     assert int(plan.placement[0]) not in new.placement
     assert not np.isin(new.placement, died_earlier).any()
     assert (new.request.p_f[died_earlier] == 1.0).all()
+
+
+@pytest.mark.parametrize("lazy", [False, True])
+@pytest.mark.parametrize("route_faulty", [True, False])
+def test_tofa_fallback_stays_on_allocatable_nodes(route_faulty, lazy):
+    """Fewer healthy free nodes than ranks: tofa falls back to selecting
+    from the whole fault-weighted topology, and must still never pick a
+    node another job holds — busy or fault flavored, dense or lazy."""
+    from repro.core.state import ClusterState
+    topo = TorusTopology((4, 4, 4))
+    p_f = np.zeros(64)
+    p_f[1::2] = 0.3
+    busy = np.arange(40)                # 24 free: 12 healthy, 12 faulty
+    view = ClusterState.healthy(64).with_outage(p_f).overlay(
+        busy, route_faulty=route_faulty)
+    req = PlacementRequest(comm=npb_dt_like(18).comm, topology=topo,
+                           state=view)
+    engine = PlacementEngine(lazy_threshold=32 if lazy else None)
+    plan = engine.place(req, policy="tofa", rng=np.random.default_rng(0))
+    assert len(set(plan.placement.tolist())) == 18
+    assert np.isin(plan.placement, view.available_ids()).all()
+
+
+def test_tofa_lazy_last_resort_stays_on_allocatable_nodes():
+    """With no hierarchy to grow a ball from, the lazy candidate search
+    falls back to frontier growth over the whole metric; that growth too
+    must only take allocatable nodes."""
+    from repro.core.policies import PolicyContext
+    from repro.core.policies.tofa import TofaMultilevelPolicy
+    topo = TorusTopology((4, 4, 4))
+    available = np.arange(0, 64, 3)     # 22 free nodes, none consecutive
+    p_f = np.ones(64)
+    p_f[available] = 0.0
+    W = topo.lazy_distance(p_f)
+    ctx = PolicyContext(request=None, G_w=npb_dt_like(18).comm.weights(),
+                        coords=topo.coords_array(), hops=None, p_f=p_f,
+                        available=available, rng=np.random.default_rng(0),
+                        _weights=W)
+    used_window, candidates = TofaMultilevelPolicy._candidates_lazy(ctx)
+    assert not used_window and len(candidates) == 1
+    assert len(candidates[0]) == 18
+    assert np.isin(candidates[0], available).all()
