@@ -115,8 +115,8 @@ class JaxBackend:
         # (tests/test_state.py asserts zero new transfers across a warm
         # state-churn sequence); ``numpy_fallbacks`` counts the calls the
         # dispatch layer (``mapping._jax_kernels``) turned away to NumPy.
-        self.stats = {"transfers": 0, "transfer_hits": 0,
-                      "sharded_dispatches": 0, "numpy_fallbacks": 0}
+        self.stats = {"transfers": 0, "sharded_dispatches": 0,
+                      "numpy_fallbacks": 0}
 
     def __repr__(self) -> str:  # pragma: no cover - debugging aid
         return (f"<backend {self.name} dtype={self.dtype} "
@@ -163,7 +163,6 @@ class JaxBackend:
         key = (id(arr), self.dtype)
         hit = self._device.get(key)
         if hit is not None:
-            self.stats["transfer_hits"] += 1
             self._device.move_to_end(key)
             return hit[1]
         self.stats["transfers"] += 1

@@ -31,7 +31,6 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import os
-import time
 from collections import OrderedDict
 from typing import (Any, Iterable, Optional, Protocol, Sequence, Union,
                     runtime_checkable)
@@ -39,6 +38,7 @@ from typing import (Any, Iterable, Optional, Protocol, Sequence, Union,
 import numpy as np
 
 from . import backend as _backend
+from . import spans as _spans
 from .comm_graph import CommGraph
 from .lazydist import is_lazy
 from .mapping import avg_dilation, hop_bytes
@@ -272,8 +272,10 @@ class PlacementPlan:
     hop_bytes_fault_weighted: Optional[float]  # under Eq. 1 weights, if computed
     faulty_nodes_used: int          # processes placed on p_f > 0 nodes
     used_consecutive_window: bool   # TOFA step 10 succeeded?
-    wall_time_s: float              # mapper wall-clock for this plan
+    wall_time_s: float              # the engine call's wall-clock, end to end
     provenance: str = "place"       # place | replace-incremental | replace-full
+    # the call's phases: span name -> (count, seconds); see core/spans.py
+    spans: dict = dataclasses.field(default_factory=dict)
 
     @property
     def n_procs(self) -> int:
@@ -356,7 +358,7 @@ class PlacementEngine:
         self._pinned: OrderedDict[int, Topology] = OrderedDict()
         self._max_weights = max_cached_weights
         self._max_topos = max_cached_topologies
-        self.stats = {"hop_hits": 0, "hop_misses": 0,
+        self.stats = {"hop_misses": 0,
                       "weight_hits": 0, "weight_misses": 0,
                       "shared_hits": 0, "shared_misses": 0,
                       "weight_delta_updates": 0,
@@ -396,9 +398,7 @@ class PlacementEngine:
 
     def hops(self, topo: Topology):
         key = self._topo_key(topo)
-        if key in self._hops:
-            self.stats["hop_hits"] += 1
-        else:
+        if key not in self._hops:
             self.stats["hop_misses"] += 1
         build = (topo.lazy_distance if self._use_lazy(topo)
                  else topo.hop_matrix)
@@ -467,7 +467,8 @@ class PlacementEngine:
             # per (topology, state) entry, no delta machinery needed
             # (entries are computed per access, so there is no stored
             # base to refresh)
-            return topo.lazy_distance(p_f, straggler=straggler)
+            with _spans.span("weights", kind="lazy"):
+                return topo.lazy_distance(p_f, straggler=straggler)
         n = topo.n_nodes
         flags = (np.zeros(n, dtype=bool) if p_f is None
                  else np.asarray(p_f) > 0)
@@ -488,12 +489,14 @@ class PlacementEngine:
             if n_changed == 0:
                 W = W_prev
             elif n_changed <= max(1, n // 4):
-                W = topo.weight_matrix_update(
-                    W_prev, np.flatnonzero(changed), p_f,
-                    straggler=straggler)
+                with _spans.span("weights", kind="delta"):
+                    W = topo.weight_matrix_update(
+                        W_prev, np.flatnonzero(changed), p_f,
+                        straggler=straggler)
                 self.stats["weight_delta_updates"] += 1
         if W is None:
-            W = topo.weight_matrix(p_f, straggler=straggler)
+            with _spans.span("weights", kind="full"):
+                W = topo.weight_matrix(p_f, straggler=straggler)
         self._weights_last[topo_key] = (flags, slow, W)
         self._weights_last.move_to_end(topo_key)
         while len(self._weights_last) > self._max_topos:
@@ -561,15 +564,15 @@ class PlacementEngine:
     def place(self, request: PlacementRequest, policy: Optional[str] = None,
               *, rng: Optional[np.random.Generator] = None) -> PlacementPlan:
         """Run one registered policy against one request."""
-        with self._backend_ctx():
-            return self._place(request, policy, rng=rng)
+        with _spans.record("place") as rec, self._backend_ctx():
+            plan = self._place(request, policy, rng=rng)
+        return _stamped(plan, rec.closed)
 
     def _place(self, request: PlacementRequest, policy: Optional[str] = None,
                *, rng: Optional[np.random.Generator] = None) -> PlacementPlan:
         name = policy or self.default_policy
         pol = get_policy(name)
         rng = rng if rng is not None else np.random.default_rng(request.seed)
-        t0 = time.perf_counter()
         topo = request.topology
         p_f = request.effective_p_f()
         route_p = request.route_p_f()
@@ -586,9 +589,8 @@ class PlacementEngine:
             avail_token=request.state.key,
         )
         out = pol.place(ctx)
-        wall = time.perf_counter() - t0
         return self._plan(request, name, np.asarray(out.placement),
-                          out.used_consecutive_window, ctx, wall, "place")
+                          out.used_consecutive_window, ctx, "place")
 
     def compare(self, request: PlacementRequest,
                 policies: Optional[Iterable[str]] = None,
@@ -634,6 +636,10 @@ class PlacementEngine:
         nodes as certain outages (historical behavior); the placement
         service passes ``False`` so occupied nodes stay valid routers and
         the whole drain tick shares epoch-keyed weight matrices.
+
+        The batch is one ``place_many`` record (:mod:`repro.core.spans`)
+        holding one ``place`` record per request, so each plan carries
+        its own phases and ``wall_time_s``.
         """
         requests = list(requests)
         if policy is None or isinstance(policy, str):
@@ -645,14 +651,16 @@ class PlacementEngine:
                     f"{len(policies)} policies for {len(requests)} requests")
         plans: list[PlacementPlan] = []
         taken: dict[Any, np.ndarray] = {}   # topo key -> occupied node ids
-        with self._backend_ctx():
+        with _spans.record("place_many"), self._backend_ctx():
             for req, pol in zip(requests, policies):
                 key = self._topo_key(req.topology)
                 if exclusive:
                     busy = taken.get(key)
                     if busy is not None and busy.size:
                         req = req.restrict(busy, route_faulty=route_faulty)
-                plan = self._place(req, policy=pol, rng=rng)
+                with _spans.record("place") as rec:
+                    plan = self._place(req, policy=pol, rng=rng)
+                plan = _stamped(plan, rec.closed)
                 plans.append(plan)
                 if exclusive:
                     prev = taken.get(key)
@@ -693,9 +701,10 @@ class PlacementEngine:
         deprecation shim equivalent to passing the interned state they
         describe.
         """
-        with self._backend_ctx():
-            return self._replace(plan, failed_nodes, state=state, rng=rng,
-                                 full=full, p_f=p_f, available=available)
+        with _spans.record("replace") as rec, self._backend_ctx():
+            out = self._replace(plan, failed_nodes, state=state, rng=rng,
+                                full=full, p_f=p_f, available=available)
+        return out if out is plan else _stamped(out, rec.closed)
 
     def _replace(self, plan: PlacementPlan,
                  failed_nodes: Union[Sequence[int], np.ndarray, None] = None,
@@ -772,7 +781,6 @@ class PlacementEngine:
             fresh = self._place(new_req, policy=plan.policy, rng=rng)
             return dataclasses.replace(fresh, provenance="replace-full")
 
-        t0 = time.perf_counter()
         p_eff = new_req.effective_p_f()
         ctx = PolicyContext(
             request=new_req,
@@ -808,14 +816,14 @@ class PlacementEngine:
             placement[i] = best
             settled[i] = True
             free = free[free != best]
-        wall = time.perf_counter() - t0
         return self._plan(new_req, plan.policy, placement,
-                          plan.used_consecutive_window, ctx, wall,
+                          plan.used_consecutive_window, ctx,
                           "replace-incremental")
 
     # ------------------------------------------------------------ internals
-    def _plan(self, request, policy, placement, used_window, ctx, wall,
+    def _plan(self, request, policy, placement, used_window, ctx,
               provenance) -> PlacementPlan:
+        """The plan; the public call stamps its time and spans on it."""
         weighted = (hop_bytes(ctx.G_w, ctx.weights, placement)
                     if ctx.weights_computed else None)
         return PlacementPlan(
@@ -827,9 +835,16 @@ class PlacementEngine:
             hop_bytes_fault_weighted=weighted,
             faulty_nodes_used=int((ctx.p_f[placement] > 0).sum()),
             used_consecutive_window=used_window,
-            wall_time_s=wall,
+            wall_time_s=0.0,
             provenance=provenance,
         )
+
+
+def _stamped(plan: PlacementPlan, rec: _spans.Record) -> PlacementPlan:
+    """``plan`` with its engine call's record: the record's total as
+    ``wall_time_s`` and its spans."""
+    return dataclasses.replace(plan, wall_time_s=rec.total_s,
+                               spans=rec.spans)
 
 
 _DEFAULT_ENGINE: Optional[PlacementEngine] = None

@@ -64,6 +64,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from . import backend as _backend
+from . import spans as _spans
 
 # swap acceptance threshold — identical to the NumPy kernel
 _GAIN_EPS = 1e-9
@@ -484,16 +485,24 @@ def refine_many(G_w: np.ndarray, D: np.ndarray, placements: np.ndarray,
     them; the batch axis is padded to a device multiple by repeating the
     last candidate (refinement is deterministic per candidate, so the
     duplicates are free of side effects and sliced off).
+
+    Spans (:mod:`repro.core.spans`): ``refine`` (args ``B``, ``n``) is
+    split into ``refine.prepare`` (host work up to the enqueue: padding,
+    sparse rows, device copies, the launch) and ``refine.wait`` (the
+    device's work and the copy back to the host).
     """
     be = _be()
     B, n = np.atleast_2d(placements).shape
-    with be.scope():
-        run, args, n_dev = refine_program(G_w, D, placements, max_passes,
-                                          movers, extra_passes)
-        if n_dev > 1:
-            be.stats["sharded_dispatches"] += 1
-        out = run(*args)
-    out = np.asarray(out)[:B, :n].astype(np.int64)
+    with _spans.span("refine", B=B, n=n):
+        with be.scope(), _spans.span("refine.prepare"):
+            run, args, n_dev = refine_program(G_w, D, placements,
+                                              max_passes, movers,
+                                              extra_passes)
+            if n_dev > 1:
+                be.stats["sharded_dispatches"] += 1
+            out = run(*args)
+        with _spans.span("refine.wait"):
+            out = np.asarray(out)[:B, :n].astype(np.int64)
     return out if np.asarray(placements).ndim == 2 else out[0]
 
 

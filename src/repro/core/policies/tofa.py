@@ -26,6 +26,7 @@ from __future__ import annotations
 import numpy as np
 
 from .. import mapping, multilevel
+from .. import spans as _spans
 from ..topology import find_consecutive_healthy
 from .base import PolicyContext, PolicyOutput, register_policy
 
@@ -49,6 +50,15 @@ def _healthy_window_starts(p_f: np.ndarray, count: int) -> list[int]:
             bad = i + int(np.argmax(~healthy[i:i + count]))
             i = bad + 1
     return starts
+
+
+def _memo_candidates(ctx: PolicyContext, key, build):
+    """``build()``'s candidate node sets, memoised per health state by
+    ``ctx.memo``; a miss is the ``candidates`` span."""
+    def timed():
+        with _spans.span("candidates"):
+            return build()
+    return ctx.memo(key, timed)
 
 
 @register_policy("tofa")
@@ -75,8 +85,8 @@ class TofaPolicy:
         # per-(topology, health) shared cache: batch simulations placing
         # hundreds of same-size jobs against one health snapshot grow the
         # window/ball candidates once.
-        used_window, candidates = ctx.memo(
-            ("tofa-candidates", n), lambda: self._candidates(ctx, W))
+        used_window, candidates = _memo_candidates(
+            ctx, ("tofa-candidates", n), lambda: self._candidates(ctx, W))
 
         if used_window:
             placement = mapping.best_map(G_w, candidates, coords, W, rng)
@@ -160,8 +170,8 @@ class TofaMultilevelPolicy(TofaPolicy):
         if n <= self.COARSE_TARGET:
             # coarsening would be a no-op: run the flat policy unchanged
             return TofaPolicy.place(self, ctx)
-        used_window, candidates = ctx.memo(
-            ("tofa-candidates", n), lambda: self._candidates(ctx, W))
+        used_window, candidates = _memo_candidates(
+            ctx, ("tofa-candidates", n), lambda: self._candidates(ctx, W))
         placements = np.stack([
             multilevel.multilevel_map(ctx.G_w, nodes, ctx.coords, D=W,
                                       rng=ctx.rng,
@@ -174,8 +184,8 @@ class TofaMultilevelPolicy(TofaPolicy):
     @classmethod
     def _place_lazy(cls, ctx: PolicyContext, W) -> PolicyOutput:
         n = ctx.n_procs
-        used_window, candidates = ctx.memo(
-            ("tofa-ml-candidates", n), lambda: cls._candidates_lazy(ctx))
+        used_window, candidates = _memo_candidates(
+            ctx, ("tofa-ml-candidates", n), lambda: cls._candidates_lazy(ctx))
         placements = np.stack([
             multilevel.multilevel_map(ctx.G_w, nodes, ctx.coords, D=W,
                                       rng=ctx.rng,
