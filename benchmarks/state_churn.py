@@ -16,10 +16,11 @@ arrive every ``churn_every`` rounds, and reports:
 * ``epochs``       — distinct state versions minted (should track the
                      churn events, not the heartbeat rate);
 * ``place_warm_ms`` / ``place_cold_ms`` — median warm vs post-churn
-                     placement latency (delta weight refreshes keep even
-                     the cold ones cheap);
-* ``weight_delta_updates`` — how many cold derivations took the row-wise
-                     refresh path instead of a full re-derivation.
+                     placement latency (the torus's vectorised route walk
+                     keeps even the cold ones cheap);
+* ``weight_delta_updates`` — how many cold derivations took a row-wise
+                     refresh path instead of a full re-derivation (0 on
+                     the torus, which derives in full).
 
 ``--check`` is the CI gate: ``hit_rate`` must stay >= the committed
 floor (0.95) on the drain-sweep preset.  ``--write --label <name>``
@@ -67,7 +68,7 @@ def run_churn(fast: bool = False, seed: int = 0) -> dict:
     wl = npb_dt_like(12 if fast else 16)
     # churn alternates flaky victims (pattern-preserving: the weight
     # matrix is literally unchanged, only the epoch moves) and healthy
-    # victims (pattern flip: exercises the row-wise delta refresh)
+    # victims (pattern flip: a fresh Eq. (1) derivation)
     healthy = np.setdiff1d(np.arange(topo.n_nodes), flaky)
     victims = np.empty(2 * min(len(flaky), len(healthy)), dtype=np.int64)
     victims[0::2] = flaky[:len(victims) // 2]

@@ -16,9 +16,12 @@ so schedulers and batch simulators that place thousands of jobs against
 a slowly-drifting health feed hit warm caches until health actually
 changes, with no byte-hashing or quantization of the raw vectors.  When
 a health change does arrive, topologies that implement
-``weight_matrix_update`` get a *row-wise delta refresh*: only the matrix
-entries whose routes touch a changed node are recomputed (bit-identical
-to a full derivation, differentially tested).
+``weight_matrix_update`` (the fat-tree and the dragonfly, whose weights
+are endpoint-form) get a *row-wise delta refresh*: only the rows and
+columns of the changed nodes are recomputed (bit-identical to a full
+derivation, differentially tested).  The torus has no delta path: its
+full derivation walks every route at once, vectorised over all pairs,
+which costs less than finding the pairs a change touches.
 
 :meth:`PlacementEngine.replace` performs incremental re-placement when a
 state diff (or an explicit failed set) invalidates a running plan, with
@@ -92,7 +95,8 @@ class Topology(Protocol):
     layer), :class:`~repro.core.fattree.FatTreeTopology` (k-ary Clos).
     Topologies may additionally implement
     ``weight_matrix_update(W_prev, changed, p_f, straggler=...)`` to
-    refresh only the entries a small health delta invalidates.
+    refresh only the entries a small health delta invalidates (the
+    fat-tree and the dragonfly do; the torus derives in full).
     """
 
     @property
@@ -457,11 +461,13 @@ class PlacementEngine:
     def _derive_weights(self, topo: Topology,
                         p_f: Optional[np.ndarray],
                         straggler: Optional[np.ndarray]) -> np.ndarray:
-        """Full derivation, or a row-wise delta refresh from the last
-        derived matrix when the topology supports it and the health delta
-        is small.  Delta results are bit-identical to full derivation
-        (only entries whose routes touch a changed node can differ, and
-        exactly those are recomputed with the same formula)."""
+        """The last derived matrix when the penalty flags and slowdowns
+        are unchanged, a row-wise delta refresh of it when the topology
+        has ``weight_matrix_update`` (fat-tree, dragonfly) and the health
+        delta is small, else a full derivation.  Delta results are
+        bit-identical to full derivation (only entries whose paths touch
+        a changed node can differ, and exactly those are recomputed with
+        the same formula)."""
         if self._use_lazy(topo):
             # implicit regime: the adapter IS the weight matrix — O(N)
             # per (topology, state) entry, no delta machinery needed
@@ -478,7 +484,7 @@ class PlacementEngine:
         topo_key = self._topo_key(topo)
         last = self._weights_last.get(topo_key)
         W = None
-        if last is not None and hasattr(topo, "weight_matrix_update"):
+        if last is not None:
             prev_flags, prev_slow, W_prev = last
             changed = flags != prev_flags
             if slow is not None or prev_slow is not None:
@@ -488,7 +494,8 @@ class PlacementEngine:
             n_changed = int(changed.sum())
             if n_changed == 0:
                 W = W_prev
-            elif n_changed <= max(1, n // 4):
+            elif (n_changed <= max(1, n // 4)
+                  and hasattr(topo, "weight_matrix_update")):
                 with _spans.span("weights", kind="delta"):
                     W = topo.weight_matrix_update(
                         W_prev, np.flatnonzero(changed), p_f,

@@ -20,9 +20,11 @@ pipeline may silently densify the matrix.
 Fault/straggler weighting stays **exact**, not approximate.  For the
 torus, the Eq. (1) extra terms are nonzero only for pairs whose
 dimension-ordered route touches a penalised node; the adapter flags
-candidate pairs with the same vectorized route-membership conditions as
-:meth:`TorusTopology.pairs_through` and walks the route scalar-exactly
-for flagged pairs only — O(f * n^(1/ndim)) work per requested row for f
+candidate pairs with vectorized route-membership conditions (a node is
+on the dimension-ordered route when, for some dimension k, its prefix
+matches v, its suffix matches u, and its k-th coordinate lies on the
+shortest wrap path) and walks the route scalar-exactly for flagged
+pairs only — O(f * n^(1/ndim)) work per requested row for f
 penalised nodes, instead of O(n^2 * hops) for the dense derivation.
 Fat-tree weighting is endpoint-form and trivially elementwise.
 
@@ -173,9 +175,13 @@ class TorusLazyDistance(LazyDistance):
 
     def _on_route_any(self, u, v, cu, cv) -> np.ndarray:
         """Pairs whose dimension-ordered route u -> v touches any
-        penalised/straggling node — the elementwise form of
-        :meth:`TorusTopology.pairs_through` (same membership conditions,
-        evaluated per requested pair instead of over the full (n, n))."""
+        penalised/straggling node, evaluated per requested pair.
+
+        While the route corrects dimension ``k``, the visited nodes have
+        coordinates ``(v[<k], path(u[k] -> v[k]), u[>k])``, so node x is
+        on route(u, v) iff for some k the prefix of x matches v, the
+        suffix matches u, and ``x[k]`` lies on the shortest wrap path in
+        dimension k (ties toward +1, as :meth:`TorusTopology.route`)."""
         ndim = len(self.dims)
         aff = np.zeros(u.shape, dtype=bool)
         for x in self._interesting:
@@ -199,9 +205,11 @@ class TorusLazyDistance(LazyDistance):
         return aff & (u != v)                    # empty routes touch nothing
 
     def _route_extra(self, u: int, v: int) -> float:
-        """Exact Eq. (1) extra for one pair: the same scalar route walk as
-        :meth:`TorusTopology.weight_matrix` (memoised — refinement re-reads
-        the same flagged pairs many times)."""
+        """Exact Eq. (1) extra for one pair: a scalar walk of
+        :meth:`TorusTopology.route_nodes` that adds the same link costs in
+        the same order as :meth:`TorusTopology.weight_matrix`'s vectorised
+        walk (memoised — refinement re-reads the same flagged pairs many
+        times)."""
         hit = self._pair_cache.get((u, v))
         if hit is not None:
             return hit

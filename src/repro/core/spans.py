@@ -13,7 +13,7 @@ A record opens at each public engine call (``place``, ``replace``,
 nested calls and threads keep records of their own.  A record's own
 span (its name) is a span of the record around it, if any.  A span
 opened outside any record goes to the trace only.  Closed records are
-kept in a bounded deque, :func:`recent`: the last 256, newest last.
+kept in a bounded deque, :func:`recent`: the last 4096, newest last.
 
 No switch: with no profiler collecting, a span skips the annotation and
 costs a few microseconds.
@@ -27,7 +27,9 @@ import sys
 import time
 from typing import Optional
 
-RECENT_MAX = 256
+# bounded, yet enough for every placement of a one-minute closed loop at
+# tens of placements a second (an 8x8x8 torus places ~7 a second)
+RECENT_MAX = 4096
 
 _OPEN: contextvars.ContextVar[tuple] = contextvars.ContextVar(
     "repro_span_records", default=())

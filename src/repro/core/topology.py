@@ -29,6 +29,10 @@ import numpy as np
 
 FAULT_PENALTY = 100.0  # the paper's "100" in Eq. (1)
 
+# pairs per row block of the vectorised route walk in weight_matrix
+# (a 512-node torus is one block)
+_WEIGHT_BLOCK_ELEMS = 1 << 18
+
 
 @dataclasses.dataclass(frozen=True)
 class Link:
@@ -177,6 +181,17 @@ class TorusTopology:
         Returns an (n, n) matrix where entry (u, v) is the weight of the
         dimension-ordered route u -> v.  With no faults this equals
         ``c * hop_matrix()``.
+
+        The route is walked once for all pairs at a time: per dimension,
+        step ``i`` moves every pair whose remaining distance there exceeds
+        ``i`` one link along its shortest wrap direction (ties toward +1,
+        as :meth:`route`) and adds that link's extra cost.  A link costs
+        ``c * FAULT_PENALTY`` when either end is faulty, otherwise
+        ``c * max(s_a, s_b)`` over its ends' slowdowns.  The extras are
+        summed in route order and added to the base once, as a scalar walk
+        of :meth:`route_nodes` per pair would (bit-identical in float64,
+        differentially tested).  Rows go in blocks of about
+        ``_WEIGHT_BLOCK_ELEMS`` pairs, so the working set stays bounded.
         """
         n = self.n_nodes
         if p_f is None:
@@ -184,124 +199,52 @@ class TorusTopology:
         p_f = np.asarray(p_f, dtype=np.float64)
         base = c * self.hop_matrix()
         faulty = p_f > 0
-        slow = None
+        slow = np.zeros(n)
         if straggler is not None:
-            slow = np.asarray(straggler, dtype=np.float64)
-            if not np.any(slow > 0):
-                slow = None
-        if not faulty.any() and slow is None:
+            s = np.asarray(straggler, dtype=np.float64)
+            slow = np.where(s > 0, s, 0.0)
+        if not faulty.any() and not slow.any():
             return base
 
-        # Count, per pair, the route links that touch a penalised node.  The
-        # dimension-ordered route from u to v visits nodes u = n_0 .. n_k = v;
-        # link i touches nodes (n_i, n_{i+1}).  A node x strictly inside the
-        # route contributes to two links, an endpoint to one.
-        w = base.copy()
-        penal = np.flatnonzero(faulty)
-        penal_set = set(int(x) for x in penal)
-        slow_idx = set(np.flatnonzero(slow > 0).tolist()) if slow is not None else set()
-        interesting = penal_set | slow_idx
-        if not interesting:
-            return w
-        for u in range(n):
-            for v in range(n):
-                if u == v:
-                    continue
-                nodes = self.route_nodes(u, v)
-                extra = 0.0
-                for a, b in zip(nodes[:-1], nodes[1:]):
-                    if a in penal_set or b in penal_set:
-                        extra += c * FAULT_PENALTY
-                    elif a in slow_idx or b in slow_idx:
-                        sa = slow[a] if a in slow_idx else 0.0
-                        sb = slow[b] if b in slow_idx else 0.0
-                        extra += c * max(sa, sb)
-                w[u, v] += extra
-        return w
+        # per dimension, (n, 3) tables over the moves (+1, -1, stay): the
+        # next node's id times 3 and the cost of the link taken; "stay"
+        # costs 0 and is what a pair takes once its dimension is corrected
+        coords = self.coords_array()
+        ids = np.arange(n)
+        moves, costs = [], []
+        for k, d in enumerate(self.dims):
+            stride = int(np.prod(self.dims[k + 1:], dtype=np.int64))
+            nxt = np.empty((n, 3), dtype=np.int64)
+            for j, delta in enumerate((1, -1)):
+                nxt[:, j] = ids + ((coords[:, k] + delta) % d
+                                   - coords[:, k]) * stride
+            nxt[:, 2] = ids
+            a = ids[:, None]
+            cost = np.where(faulty[a] | faulty[nxt], c * FAULT_PENALTY,
+                            c * np.maximum(slow[a], slow[nxt]))
+            cost[:, 2] = 0.0
+            moves.append((3 * nxt).ravel())
+            costs.append(cost.ravel())
 
-    def pairs_through(self, nodes) -> np.ndarray:
-        """(n, n) bool: pairs whose dimension-ordered route touches any of
-        ``nodes`` (endpoints included).
-
-        While the route corrects dimension ``k``, the visited nodes have
-        coordinates ``(v[<k], path(u[k] -> v[k]), u[>k])`` — so node x is
-        on route(u, v) iff for some k the prefix of x matches v, the
-        suffix matches u, and ``x[k]`` lies on the shortest wrap path in
-        dimension k.  Vectorized over all pairs per probed node; used by
-        :meth:`weight_matrix_update` to bound delta refreshes to exactly
-        the entries a health change can invalidate.
-        """
-        c = self.coords_array()
-        n = self.n_nodes
-        aff = np.zeros((n, n), dtype=bool)
-        for x in np.atleast_1d(np.asarray(nodes, dtype=np.int64)):
-            xc = c[int(x)]
-            # post[k]: u-side suffix match (u[j] == x[j] for all j > k-1);
-            # post[k+1] is the constraint for dims strictly after k
-            post = np.ones((self.ndim + 1, n), dtype=bool)
-            for j in range(self.ndim - 1, -1, -1):
-                post[j] = post[j + 1] & (c[:, j] == xc[j])
-            pre = np.ones(n, dtype=bool)      # v-side prefix match (j < k)
-            for k in range(self.ndim):
-                d = self.dims[k]
-                a = c[:, k]                   # u-side coordinate, dim k
-                b = c[:, k]                   # v-side coordinate, dim k
-                fwd = (b[None, :] - a[:, None]) % d
-                bwd = (a[:, None] - b[None, :]) % d
-                on_f = ((xc[k] - a[:, None]) % d) <= fwd
-                on_b = ((a[:, None] - xc[k]) % d) <= bwd
-                on = np.where(fwd <= bwd, on_f, on_b)
-                aff |= post[k + 1][:, None] & pre[None, :] & on
-                pre = pre & (c[:, k] == xc[k])
-        np.fill_diagonal(aff, False)          # empty routes: nothing to touch
-        return aff
-
-    def weight_matrix_update(
-        self,
-        W_prev: np.ndarray,
-        changed,
-        p_f: np.ndarray | None = None,
-        c: float = 1.0,
-        straggler: np.ndarray | None = None,
-    ) -> np.ndarray:
-        """Row-wise delta refresh of :meth:`weight_matrix`.
-
-        ``W_prev`` must be the weight matrix of a health state that
-        differs from ``(p_f, straggler)`` exactly at the ``changed``
-        node ids (penalty flag or slowdown value).  Only the entries
-        whose routes touch a changed node are recomputed — with the same
-        formula as the full derivation, so the result is bit-identical
-        to ``weight_matrix(p_f, c, straggler)`` (asserted in
-        ``tests/test_state.py``).
-        """
-        changed = np.atleast_1d(np.asarray(changed, dtype=np.int64))
-        if changed.size == 0:
-            return W_prev
-        n = self.n_nodes
-        p_f = np.zeros(n) if p_f is None else np.asarray(p_f, np.float64)
-        base = c * self.hop_matrix()
-        penal_set = set(np.flatnonzero(p_f > 0).tolist())
-        slow = None
-        if straggler is not None:
-            slow = np.asarray(straggler, dtype=np.float64)
-            if not np.any(slow > 0):
-                slow = None
-        slow_idx = (set(np.flatnonzero(slow > 0).tolist())
-                    if slow is not None else set())
-        aff = self.pairs_through(changed)
-        W = W_prev.copy()
-        for u, v in zip(*np.nonzero(aff)):
-            nodes = self.route_nodes(int(u), int(v))
-            extra = 0.0
-            for a, b in zip(nodes[:-1], nodes[1:]):
-                if a in penal_set or b in penal_set:
-                    extra += c * FAULT_PENALTY
-                elif a in slow_idx or b in slow_idx:
-                    sa = slow[a] if a in slow_idx else 0.0
-                    sb = slow[b] if b in slow_idx else 0.0
-                    extra += c * max(sa, sb)
-            W[u, v] = base[u, v] + extra
-        return W
+        rows = max(1, _WEIGHT_BLOCK_ELEMS // n)
+        for r0 in range(0, n, rows):
+            u = ids[r0:r0 + rows]
+            at = np.repeat(3 * u[:, None], n, axis=1)   # 3 * current node
+            extra = np.zeros((u.size, n))
+            for k, d in enumerate(self.dims):
+                a = coords[u, k][:, None]
+                b = coords[None, :, k]
+                fwd = (b - a) % d
+                bwd = (a - b) % d
+                plus = fwd <= bwd
+                left = np.where(plus, fwd, bwd)
+                move = np.where(plus, 0, 1)
+                for i in range(d // 2):
+                    idx = at + np.where(left > i, move, 2)
+                    extra += costs[k][idx]
+                    at = moves[k][idx]
+            base[r0:r0 + rows] += extra
+        return base
 
     # ------------------------------------------------------------- sub-extract
     def submatrix(self, weights: np.ndarray, nodes: Sequence[int]) -> np.ndarray:

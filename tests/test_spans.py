@@ -60,7 +60,7 @@ def test_recent_keeps_the_last_256():
         with spans.record(f"r{i}"):
             pass
     got = spans.recent()
-    assert len(got) == spans.RECENT_MAX == 256
+    assert len(got) == spans.RECENT_MAX == 4096
     assert got[0].name == "r44"
     assert got[-1].name == f"r{spans.RECENT_MAX + 43}"
 

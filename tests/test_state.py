@@ -255,20 +255,6 @@ def test_estimator_jitter_would_have_missed_on_byte_keys():
 
 
 # ------------------------------------------------ delta weight refreshes
-def test_torus_delta_weight_update_bit_identical():
-    t = TorusTopology((4, 4, 3))
-    rng = np.random.default_rng(5)
-    prev_p = np.zeros(t.n_nodes)
-    W = t.weight_matrix(prev_p)
-    for _ in range(4):
-        p = np.zeros(t.n_nodes)
-        p[rng.choice(t.n_nodes, 4, replace=False)] = 0.2
-        changed = np.flatnonzero((p > 0) != (prev_p > 0))
-        W2 = t.weight_matrix_update(W, changed, p)
-        assert (W2 == t.weight_matrix(p)).all()
-        prev_p, W = p, W2
-
-
 def test_fattree_delta_weight_update_bit_identical():
     ft = FatTreeTopology(4)
     p0 = np.zeros(16)
@@ -282,11 +268,11 @@ def test_fattree_delta_weight_update_bit_identical():
 
 
 def test_engine_uses_delta_updates_across_churn():
-    topo = TorusTopology((4, 4, 4))
+    topo = FatTreeTopology(4)
     engine = PlacementEngine()
-    wl = npb_dt_like(12)
-    s = ClusterState.healthy(64).with_outage(
-        np.where(np.arange(64) < 4, 0.2, 0.0))
+    wl = npb_dt_like(8)
+    s = ClusterState.healthy(16).with_outage(
+        np.where(np.arange(16) < 2, 0.2, 0.0))
     rng = np.random.default_rng(0)
     full = PlacementEngine()                 # reference: fresh engine per state
     for step in range(4):
@@ -298,8 +284,33 @@ def test_engine_uses_delta_updates_across_churn():
                          policy="tofa", rng=np.random.default_rng(step))
         assert (plan.placement == ref.placement).all()
         assert plan.hop_bytes == ref.hop_bytes
-        s = s.with_health([int(rng.integers(0, 64))], NodeHealth.DOWN)
+        s = s.with_health([int(rng.integers(0, 16))], NodeHealth.DOWN)
     assert engine.cache_stats()["weight_delta_updates"] >= 2
+
+
+def test_engine_derives_torus_weights_in_full_across_churn():
+    # the torus has no delta path: every change of its penalty flags or
+    # slowdowns is one full, vectorised derivation, equal to what a fresh
+    # engine serves; a change of p_f alone reuses the last matrix
+    topo = TorusTopology((4, 4, 4))
+    engine = PlacementEngine()
+    s = ClusterState.healthy(64).with_outage(
+        np.where(np.arange(64) < 4, 0.2, 0.0))
+    rng = np.random.default_rng(0)
+    for _ in range(4):
+        p_f = s.outage_vector()
+        slow = np.zeros(64)
+        slow[rng.integers(0, 64)] = 0.5
+        W = engine.weights(topo, p_f, straggler=slow)
+        assert (W == PlacementEngine().weights(topo, p_f,
+                                               straggler=slow)).all()
+        assert (W == topo.weight_matrix(p_f, straggler=slow)).all()
+        s = s.with_health([int(rng.integers(0, 64))], NodeHealth.DOWN)
+    assert engine.weights(topo, np.where(p_f > 0, 1.0, 0.0),
+                          straggler=slow) is W
+    stats = engine.cache_stats()
+    assert stats["weight_misses"] == 5
+    assert stats["weight_delta_updates"] == 0
 
 
 # --------------------------------------------------- replace fast-path

@@ -106,3 +106,88 @@ def test_arrangements_table1():
     for a in ((4, 8, 8), (4, 4, 16), (2, 8, 16)):
         assert a in arrs
     assert all(np.prod(a) == 256 for a in arrs)
+
+
+# ------------------------------------ Eq. (1) weights: scalar route oracle
+def _scalar_weight_matrix(t, p_f=None, c=1.0, straggler=None):
+    """One scalar walk of :meth:`TorusTopology.route_nodes` per pair: the
+    loop ``weight_matrix`` ran before its route walk was vectorised."""
+    n = t.n_nodes
+    if p_f is None:
+        p_f = np.zeros(n)
+    p_f = np.asarray(p_f, dtype=np.float64)
+    base = c * t.hop_matrix()
+    faulty = p_f > 0
+    slow = None
+    if straggler is not None:
+        slow = np.asarray(straggler, dtype=np.float64)
+        if not np.any(slow > 0):
+            slow = None
+    if not faulty.any() and slow is None:
+        return base
+    w = base.copy()
+    penal = np.flatnonzero(faulty)
+    penal_set = set(int(x) for x in penal)
+    slow_idx = set(np.flatnonzero(slow > 0).tolist()) if slow is not None else set()
+    interesting = penal_set | slow_idx
+    if not interesting:
+        return w
+    for u in range(n):
+        for v in range(n):
+            if u == v:
+                continue
+            nodes = t.route_nodes(u, v)
+            extra = 0.0
+            for a, b in zip(nodes[:-1], nodes[1:]):
+                if a in penal_set or b in penal_set:
+                    extra += c * FAULT_PENALTY
+                elif a in slow_idx or b in slow_idx:
+                    sa = slow[a] if a in slow_idx else 0.0
+                    sb = slow[b] if b in slow_idx else 0.0
+                    extra += c * max(sa, sb)
+            w[u, v] += extra
+    return w
+
+
+def _health(n, seed, n_faulty=0, n_slow=0):
+    """Seeded outage and slowdown vectors with distinct slowdown values."""
+    rng = np.random.default_rng(seed)
+    p_f = np.zeros(n)
+    p_f[rng.choice(n, n_faulty, replace=False)] = 0.02
+    slow = np.zeros(n)
+    slow[rng.choice(n, n_slow, replace=False)] = rng.uniform(0.1, 1.7, n_slow)
+    return p_f, slow
+
+
+def _fault_and_straggler_on_one_link(n):
+    # node 5 both faulty and slow; its neighbour 6 slow; 9 and 10 a slow
+    # pair of unequal slowdowns (the max of the two is taken)
+    p_f, slow = np.zeros(n), np.zeros(n)
+    p_f[5] = 0.3
+    slow[[5, 6, 9, 10]] = [0.37, 0.37, 0.5, 1.25]
+    return p_f, slow
+
+
+@pytest.mark.parametrize("dims, health, c", [
+    ((8,), lambda n: _health(n, 1, n_faulty=2), 1.0),          # ties +1
+    ((7,), lambda n: _health(n, 2, n_faulty=1, n_slow=2), 1.0),  # odd
+    ((6, 5), lambda n: _health(n, 3, n_faulty=3), 1.0),
+    ((4, 4, 3), lambda n: _health(n, 4, n_faulty=4), 1.0),
+    ((8, 8, 8), lambda n: _health(n, 5, n_faulty=16), 1.0),
+    ((4, 4, 4), lambda n: _health(n, 6, n_slow=6), 1.0),       # stragglers only
+    ((6, 6), _fault_and_straggler_on_one_link, 1.0),
+    ((4, 4, 4), lambda n: _health(n, 7, n_faulty=4, n_slow=4), 2.0),
+    ((1, 5, 2), lambda n: _health(n, 8, n_faulty=2, n_slow=2), 1.0),
+    ((4, 4, 3), lambda n: (np.zeros(n), np.zeros(n)), 1.0),    # no faults
+], ids=["1d-even", "1d-odd", "2d", "4x4x3", "8x8x8-16-faults",
+        "stragglers-only", "fault-and-straggler-one-link", "c2",
+        "unit-dims", "no-faults"])
+def test_weight_matrix_equals_scalar_route_walk(dims, health, c):
+    t = TorusTopology(dims)
+    p_f, slow = health(t.n_nodes)
+    w = t.weight_matrix(p_f, c=c, straggler=slow)
+    assert w.dtype == np.float64
+    assert (w == _scalar_weight_matrix(t, p_f, c=c, straggler=slow)).all()
+    if not (p_f > 0).any() and not (slow > 0).any():
+        assert (w == c * t.hop_matrix()).all()
+        assert w is not t.hop_matrix()       # the memo is never handed out
