@@ -23,17 +23,20 @@ dimension-ordered route touches a penalised node; the adapter flags
 candidate pairs with vectorized route-membership conditions (a node is
 on the dimension-ordered route when, for some dimension k, its prefix
 matches v, its suffix matches u, and its k-th coordinate lies on the
-shortest wrap path) and walks the route scalar-exactly for flagged
-pairs only — O(f * n^(1/ndim)) work per requested row for f
-penalised nodes, instead of O(n^2 * hops) for the dense derivation.
+shortest wrap path) and prices the flagged pairs with one vectorised
+route walk (:meth:`TorusTopology.route_extras`, the walk of the dense
+``weight_matrix``), in the program span ``distance.extras``.
 Fat-tree weighting is endpoint-form and trivially elementwise.
 
-The healthy uniform-cost torus case — and the fat-tree in *every*
-health state, its weighting being endpoint-form — additionally exposes
-an ``implicit`` spec (coordinates + metric kind + scale + optional
-penalty vector) that lets the jax backend compute distances in-kernel
-(:mod:`repro.kernels.hop_dist`) instead of going through
-``__getitem__`` at all.
+Both adapters also expose an ``implicit`` spec (coordinates + metric
+kind + scale + the health state's penalty data) that lets the jax
+backend compute distances in-kernel (:mod:`repro.kernels.hop_dist`)
+instead of going through ``__getitem__`` at all: the fat-tree in every
+health state (a per-endpoint penalty vector), the torus in every health
+state without stragglers (the table of penalised coordinates, from
+which the kernel counts a route's penalised links in closed form).  A
+straggling torus prices links by slowdown, which the kernel does not
+model: its ``implicit`` is ``None`` and the NumPy kernels serve it.
 """
 from __future__ import annotations
 
@@ -44,17 +47,23 @@ import numpy as np
 
 from repro.kernels.hop_dist.ops import torus_hop_np
 
+from . import spans as _spans
+
 
 @dataclasses.dataclass(frozen=True)
 class ImplicitSpec:
     """What the jax backend needs to compute distances in-kernel:
-    per-node integer coordinates, a static metric spec, a uniform scale.
+    per-node integer coordinates, a static metric spec, a uniform scale,
+    and the health state's penalty data.
 
-    ``kind="torus"`` interprets ``coords`` against wraparound ``dims``;
-    ``kind="fattree"`` interprets them as (pod, edge, host) triples with
-    ``dims=()`` and carries the per-node endpoint ``penalty`` vector
-    (zeros when healthy — always present so the backend's identity-keyed
-    device-transfer cache has a stable array to pin).
+    ``kind="torus"`` interprets ``coords`` against wraparound ``dims``
+    and carries ``penalised``, the (ndim, F) table of the penalised
+    nodes' coordinates, padded to a power of two F with -1 (F = 0 when
+    healthy); ``kind="fattree"`` interprets them as (pod, edge, host)
+    triples with ``dims=()`` and carries the per-node endpoint
+    ``penalty`` vector (zeros when healthy).  Both are always present,
+    so the backend's identity-keyed device-transfer cache has a stable
+    array to pin.
     """
 
     coords: np.ndarray          # (N, ndim) float64 — stable identity for
@@ -63,6 +72,7 @@ class ImplicitSpec:
     scale: float
     kind: str = "torus"
     penalty: Optional[np.ndarray] = None    # (N,) float64, fat-tree only
+    penalised: Optional[np.ndarray] = None  # (ndim, F) float64, torus only
 
 
 class LazyDistance:
@@ -94,7 +104,7 @@ class LazyDistance:
     @property
     def implicit(self) -> Optional[ImplicitSpec]:
         """In-kernel computation spec, or None when only ``__getitem__``
-        applies (faults, stragglers, non-torus)."""
+        applies (a straggling torus)."""
         return None
 
     def _axis(self, key, n: int) -> np.ndarray:
@@ -143,12 +153,13 @@ class TorusLazyDistance(LazyDistance):
         self._slow = slow
         slow_mask = np.zeros(topo.n_nodes, bool) if slow is None else slow > 0
         self._interesting = np.flatnonzero(penal | slow_mask)
-        self._pair_cache: dict[tuple[int, int], float] = {}
+        self._links = None        # link cost tables, built on first use
         self._spec = None
-        if self._interesting.size == 0:
+        if slow is None:
             self._spec = ImplicitSpec(
                 coords=self.coords.astype(np.float64),
-                dims=self.dims, scale=self.c)
+                dims=self.dims, scale=self.c,
+                penalised=penalised_table(self.coords[penal]))
 
     @property
     def implicit(self) -> Optional[ImplicitSpec]:
@@ -165,12 +176,10 @@ class TorusLazyDistance(LazyDistance):
             return out
         out = np.ascontiguousarray(out)
         flat = np.flatnonzero(flagged.ravel())
-        uu = u.ravel()[flat]
-        vv = v.ravel()[flat]
-        extra = np.fromiter(
-            (self._route_extra(int(a), int(b)) for a, b in zip(uu, vv)),
-            dtype=np.float64, count=flat.size)
-        out.ravel()[flat] += extra
+        with _spans.span("distance.extras", pairs=int(u.size),
+                         flagged=int(flat.size)):
+            out.ravel()[flat] += self._route_extra(u.ravel()[flat],
+                                                   v.ravel()[flat])
         return out
 
     def _on_route_any(self, u, v, cu, cv) -> np.ndarray:
@@ -204,30 +213,26 @@ class TorusLazyDistance(LazyDistance):
                 pre = pre & (cv[..., k] == xc[k])
         return aff & (u != v)                    # empty routes touch nothing
 
-    def _route_extra(self, u: int, v: int) -> float:
-        """Exact Eq. (1) extra for one pair: a scalar walk of
-        :meth:`TorusTopology.route_nodes` that adds the same link costs in
-        the same order as :meth:`TorusTopology.weight_matrix`'s vectorised
-        walk (memoised — refinement re-reads the same flagged pairs many
-        times)."""
-        hit = self._pair_cache.get((u, v))
-        if hit is not None:
-            return hit
-        penal = self._penal
-        slow = self._slow
-        c = self.c
-        from .topology import FAULT_PENALTY
-        nodes = self.topo.route_nodes(u, v)
-        extra = 0.0
-        for a, b in zip(nodes[:-1], nodes[1:]):
-            if penal[a] or penal[b]:
-                extra += c * FAULT_PENALTY
-            elif slow is not None and (slow[a] > 0 or slow[b] > 0):
-                extra += c * max(slow[a], slow[b])
-        if len(self._pair_cache) > 2_000_000:    # bound the memo
-            self._pair_cache.clear()
-        self._pair_cache[(u, v)] = extra
-        return extra
+    def _route_extra(self, u: np.ndarray, v: np.ndarray) -> np.ndarray:
+        """Exact Eq. (1) extras of the pairs ``u -> v`` (flat id arrays):
+        one vectorised walk of their routes that adds the same link costs
+        in the same order as :meth:`TorusTopology.weight_matrix`."""
+        if self._links is None:
+            slow = (np.zeros(self.shape[0]) if self._slow is None
+                    else np.where(self._slow > 0, self._slow, 0.0))
+            self._links = self.topo.link_costs(self._penal, slow, self.c)
+        return self.topo.route_extras(u, v, self._links)
+
+
+def penalised_table(coords: np.ndarray) -> np.ndarray:
+    """(F, ndim) coordinates of the penalised nodes -> the (ndim, F_pad)
+    float64 table of :class:`ImplicitSpec`, F_pad the next power of two
+    (0 stays 0) and the padding columns -1, which match no node."""
+    f, ndim = coords.shape
+    width = 0 if f == 0 else 1 << (f - 1).bit_length()
+    out = np.full((ndim, width), -1.0)
+    out[:, :f] = coords.T
+    return out
 
 
 class FatTreeLazyDistance(LazyDistance):
@@ -238,7 +243,7 @@ class FatTreeLazyDistance(LazyDistance):
     Because the fault/straggler weighting is a per-endpoint penalty
     gather — no route walks — the adapter exposes an ``implicit`` spec
     for **every** health state, so the jax backend compiles fat-tree
-    distances in-kernel even under faults (tori only qualify healthy).
+    distances in-kernel even under faults and stragglers.
     """
 
     def __init__(self, topo, p_f: Optional[np.ndarray] = None,

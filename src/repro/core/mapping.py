@@ -46,8 +46,8 @@ jit-compiled kernels of :mod:`repro.core.mapping_jax` — decision-identical
 at float64 (bit-identical placements for the integer-weighted in-tree
 workloads), with all candidate refinements of one mapping call batched
 into a single device dispatch.  Asymmetric guest matrices (outside the
-CommGraph convention) and lazy distances without an implicit spec fall
-back to the NumPy kernels, counted in the backend's
+CommGraph convention) and lazy distances without an implicit spec (a
+straggling torus) fall back to the NumPy kernels, counted in the backend's
 ``stats["numpy_fallbacks"]``.  Inside
 ``use_reference_impl`` the retained scalar loops always run, regardless
 of backend — they are the fixed baseline.
@@ -67,8 +67,9 @@ def _jax_kernels(G_w: np.ndarray | None = None, D=None):
     call, else None (numpy path).  ``G_w`` adds the symmetric-guest
     check for guest-dependent kernels; ``D`` adds the lazy-distance
     check — a lazy adapter is served only when the backend can compute
-    its entries in-kernel (implicit torus), otherwise the NumPy kernels
-    run against the adapter's ``__getitem__``.  Each such turn-away is
+    its entries in-kernel (a fat-tree in any health state, a torus with
+    no stragglers, faulty or not), otherwise the NumPy kernels run
+    against the adapter's ``__getitem__``.  Each such turn-away is
     counted in the jax backend's ``stats["numpy_fallbacks"]``."""
     be = _backend.active()
     if not getattr(be, "is_jax", False):

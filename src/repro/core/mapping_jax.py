@@ -117,20 +117,24 @@ def lazy_supported(D) -> bool:
     """A lazy distance adapter is served by this module only when it
     exposes an implicit spec — distances are then computed in-kernel
     (:mod:`repro.kernels.hop_dist`), never gathered from a stored matrix.
-    Healthy uniform tori and fat-trees in *any* health state qualify
-    (fat-tree fault/straggler weighting is endpoint-form, so it jits as a
-    penalty-vector gather); fault-weighted tori need scalar route walks
-    and run the NumPy kernels instead."""
+    Fat-trees in *any* health state qualify (fault/straggler weighting
+    is endpoint-form, so it jits as a penalty-vector gather), and tori
+    in any health state without stragglers (Eq. (1)'s penalised links are
+    counted in closed form from the table of penalised coordinates);
+    straggling tori price links by slowdown and run the NumPy kernels
+    instead."""
     return getattr(D, "implicit", None) is not None
 
 
 def _dist_fns(Ds, dims, scale):
     """The two distance accessors of the refine/score loops, closed over
-    either a dense (N, N) matrix (``dims is None``), an (N, ndim)
-    coordinate table with static torus ``dims``, or — when ``dims`` is
-    the static marker ``("fattree",)`` — a ``(coords, penalty)`` pair
-    implementing the endpoint-form fat-tree metric
-    (:class:`repro.core.lazydist.FatTreeLazyDistance`)."""
+    either a dense (N, N) matrix (``dims is None``), a ``(coords,
+    penalised)`` pair with static torus ``dims`` — the (N, ndim)
+    coordinate table and the (ndim, F) table of penalised coordinates of
+    :class:`repro.core.lazydist.TorusLazyDistance` (F = 0 when healthy)
+    — or, when ``dims`` is the static marker ``("fattree",)``, a
+    ``(coords, penalty)`` pair implementing the endpoint-form fat-tree
+    metric (:class:`repro.core.lazydist.FatTreeLazyDistance`)."""
     if dims is None:
         def dist_pairs(a, b):
             return Ds[a, b]
@@ -162,18 +166,37 @@ def _dist_fns(Ds, dims, scale):
         dist_pairs.all_pairs = _all_pairs
     else:
         from repro.kernels.hop_dist.ops import torus_hop_pairs
-        from repro.kernels.hop_dist.ref import torus_hop_elems_ref
+        from repro.kernels.hop_dist.ref import (torus_hop_elems_ref,
+                                                torus_pen_neighbours)
+        coords, pen = Ds
+        # the neighbour table is loop-invariant: built once per program
+        nb = torus_pen_neighbours(pen, dims) if pen.shape[1] else None
 
         def dist_pairs(a, b):
             # broadcast-elementwise; the all-pairs M0 build in
             # _refine_one routes through torus_hop_pairs below instead
-            return scale * torus_hop_elems_ref(Ds[a], Ds[b], dims)
+            return scale * torus_hop_elems_ref(coords[a], coords[b], dims,
+                                               pen, nb)
 
-        def dist_row(node, p):
-            return scale * torus_hop_elems_ref(Ds[node], Ds[p], dims)
+        def _pairs(u, v):
+            return scale * torus_hop_pairs(coords[u], coords[v], dims, pen)
 
-        dist_pairs.all_pairs = lambda u, v: (
-            scale * torus_hop_pairs(Ds[u], Ds[v], dims))
+        if nb is None:
+            dist_row = dist_pairs
+            dist_pairs.all_pairs = _pairs
+        else:
+            # a penalised link on the route u -> v need not lie on
+            # v -> u, so the refine reads the symmetrised weight, as the
+            # NumPy loop's gathered_row and M do; scoring keeps D as is
+            def dist_row(node, p):
+                return 0.5 * (dist_pairs(node, p) + dist_pairs(p, node))
+
+            def _all_pairs(u, v):
+                K = _pairs(u, v)
+                Kt = K if u is v else _pairs(v, u)
+                return 0.5 * (K + Kt.T)
+
+            dist_pairs.all_pairs = _all_pairs
     return dist_pairs, dist_row
 
 
@@ -243,9 +266,9 @@ def _refine_one(p0, idx, val, G_dense, Ds, n_valid, *, movers: int,
     ``idx``/``val`` (n, k) CSR-padded guest rows, ``G_dense`` (n, n) or
     a (1, 1) placeholder when the sparse path runs, ``Ds`` (N, N)
     symmetrised device-resident distances — or, with static ``dims``
-    set (implicit mode), the (N, ndim) coordinate table from which
-    every distance below is computed in-kernel, ``n_valid`` traced
-    scalar.
+    set (implicit mode), the coordinate and penalty tables from which
+    every distance below is computed in-kernel (see :func:`_dist_fns`),
+    ``n_valid`` traced scalar.
     """
     n = p0.shape[0]
     rows = jnp.arange(n, dtype=jnp.int32)
@@ -461,8 +484,8 @@ def _shard_args(n_dev: int, P_stack, *replicated):
 
 def _device_distances(D, be):
     """``(device operand, static spec key, scale)`` — the dense
-    symmetrised matrix (key ``None``), the coordinate table with static
-    torus ``dims``, or the ``(coords, penalty)`` pair keyed
+    symmetrised matrix (key ``None``), the ``(coords, penalised)`` pair
+    with static torus ``dims``, or the ``(coords, penalty)`` pair keyed
     ``("fattree",)`` in fat-tree implicit mode."""
     spec = getattr(D, "implicit", None)
     if spec is None:
@@ -471,7 +494,9 @@ def _device_distances(D, be):
         operand = (be.device_matrix(spec.coords),
                    be.device_matrix(spec.penalty))
         return operand, ("fattree",), float(spec.scale)
-    return be.device_matrix(spec.coords), spec.dims, float(spec.scale)
+    operand = (be.device_matrix(spec.coords),
+               be.device_matrix(spec.penalised))
+    return operand, spec.dims, float(spec.scale)
 
 
 def refine_many(G_w: np.ndarray, D: np.ndarray, placements: np.ndarray,

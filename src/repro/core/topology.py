@@ -182,16 +182,10 @@ class TorusTopology:
         dimension-ordered route u -> v.  With no faults this equals
         ``c * hop_matrix()``.
 
-        The route is walked once for all pairs at a time: per dimension,
-        step ``i`` moves every pair whose remaining distance there exceeds
-        ``i`` one link along its shortest wrap direction (ties toward +1,
-        as :meth:`route`) and adds that link's extra cost.  A link costs
-        ``c * FAULT_PENALTY`` when either end is faulty, otherwise
-        ``c * max(s_a, s_b)`` over its ends' slowdowns.  The extras are
-        summed in route order and added to the base once, as a scalar walk
-        of :meth:`route_nodes` per pair would (bit-identical in float64,
-        differentially tested).  Rows go in blocks of about
-        ``_WEIGHT_BLOCK_ELEMS`` pairs, so the working set stays bounded.
+        The extras of all pairs come from one route walk
+        (:meth:`route_extras` over :meth:`link_costs` tables), added to
+        the base once.  Rows go in blocks of about ``_WEIGHT_BLOCK_ELEMS``
+        pairs, so the working set stays bounded.
         """
         n = self.n_nodes
         if p_f is None:
@@ -205,10 +199,24 @@ class TorusTopology:
             slow = np.where(s > 0, s, 0.0)
         if not faulty.any() and not slow.any():
             return base
+        links = self.link_costs(faulty, slow, c)
+        ids = np.arange(n)
+        rows = max(1, _WEIGHT_BLOCK_ELEMS // n)
+        for r0 in range(0, n, rows):
+            base[r0:r0 + rows] += self.route_extras(
+                ids[r0:r0 + rows, None], ids[None, :], links)
+        return base
 
-        # per dimension, (n, 3) tables over the moves (+1, -1, stay): the
-        # next node's id times 3 and the cost of the link taken; "stay"
-        # costs 0 and is what a pair takes once its dimension is corrected
+    def link_costs(self, faulty: np.ndarray, slow: np.ndarray,
+                   c: float = 1.0) -> tuple[np.ndarray, list, list]:
+        """Per dimension, flat (n * 3) tables over the moves (+1, -1,
+        stay) of every node: the next node's id times 3 and the Eq. (1)
+        extra of the link taken.  A link costs ``c * FAULT_PENALTY`` when
+        either end is ``faulty``, otherwise ``c * max(s_a, s_b)`` over
+        its ends' ``slow`` factors (zeros: healthy); "stay" costs 0 and
+        is what a pair takes once its dimension is corrected.  Returns
+        ``(coords, moves, costs)`` for :meth:`route_extras`."""
+        n = self.n_nodes
         coords = self.coords_array()
         ids = np.arange(n)
         moves, costs = [], []
@@ -225,26 +233,39 @@ class TorusTopology:
             cost[:, 2] = 0.0
             moves.append((3 * nxt).ravel())
             costs.append(cost.ravel())
+        return coords, moves, costs
 
-        rows = max(1, _WEIGHT_BLOCK_ELEMS // n)
-        for r0 in range(0, n, rows):
-            u = ids[r0:r0 + rows]
-            at = np.repeat(3 * u[:, None], n, axis=1)   # 3 * current node
-            extra = np.zeros((u.size, n))
-            for k, d in enumerate(self.dims):
-                a = coords[u, k][:, None]
-                b = coords[None, :, k]
-                fwd = (b - a) % d
-                bwd = (a - b) % d
-                plus = fwd <= bwd
-                left = np.where(plus, fwd, bwd)
-                move = np.where(plus, 0, 1)
-                for i in range(d // 2):
-                    idx = at + np.where(left > i, move, 2)
-                    extra += costs[k][idx]
-                    at = moves[k][idx]
-            base[r0:r0 + rows] += extra
-        return base
+    def route_extras(self, u: np.ndarray, v: np.ndarray,
+                     links: tuple[np.ndarray, list, list]) -> np.ndarray:
+        """Eq. (1) extras of the routes ``u -> v`` (node id arrays,
+        broadcast together), priced by :meth:`link_costs` tables.
+
+        The route is walked once for all pairs: per dimension, step
+        ``i`` moves every pair whose remaining distance there exceeds
+        ``i`` one link along its shortest wrap direction (ties toward +1,
+        as :meth:`route`) and adds that link's extra.  The extras are
+        summed in route order, as a scalar walk of :meth:`route_nodes`
+        per pair would (bit-identical in float64, differentially
+        tested)."""
+        coords, moves, costs = links
+        u = np.asarray(u, dtype=np.int64)
+        v = np.asarray(v, dtype=np.int64)
+        shape = np.broadcast_shapes(u.shape, v.shape)
+        at = 3 * np.broadcast_to(u, shape)            # 3 * current node
+        extra = np.zeros(shape)
+        for k, d in enumerate(self.dims):
+            a = coords[u, k]
+            b = coords[v, k]
+            fwd = (b - a) % d
+            bwd = (a - b) % d
+            plus = fwd <= bwd
+            left = np.where(plus, fwd, bwd)
+            move = np.where(plus, 0, 1)
+            for i in range(d // 2):
+                idx = at + np.where(left > i, move, 2)
+                extra += costs[k][idx]
+                at = moves[k][idx]
+        return extra
 
     # ------------------------------------------------------------- sub-extract
     def submatrix(self, weights: np.ndarray, nodes: Sequence[int]) -> np.ndarray:

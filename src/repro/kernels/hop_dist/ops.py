@@ -6,6 +6,8 @@ directly from the (N, ndim) coordinate table — O(N) memory for any
 topology size.  Two metrics live here:
 
     torus:    hop(u, v) = sum_d min(|cu_d - cv_d|, dim_d - |cu_d - cv_d|)
+              (+ 100 * badlinks(u, v) on a faulty torus, given the (ndim, F)
+              table of penalised coordinates: Eq. (1), see :mod:`.ref`)
     fat-tree: hop(u, v) = 0 | 2 | 4 | 6  (same host / edge / pod / across)
 
 Three implementations share this module's dispatch:
@@ -81,20 +83,24 @@ def _resolve(impl: str) -> str:
     return impl
 
 
-def torus_hop_pairs(cu, cv, dims, impl: str = "auto"):
+def torus_hop_pairs(cu, cv, dims, pen=None, impl: str = "auto"):
     """Traceable all-pairs hop distance: (m, ndim), (k, ndim) -> (m, k).
 
     Safe to call inside other jitted code (the jitted refine loop of
     :mod:`repro.core.mapping_jax` builds its gathered-distance matrix
-    through here).
+    through here).  ``pen``, an (ndim, F) table of penalised coordinates
+    padded with -1, adds Eq. (1)'s fault term; F = 0 is the healthy
+    metric.
     """
     impl = _resolve(impl)
+    if pen is not None and pen.shape[1] == 0:
+        pen = None
     if impl in ("pallas", "pallas_interpret"):
         from repro.kernels.hop_dist.kernel import torus_hop_tpu
-        return torus_hop_tpu(cu, cv, dims,
+        return torus_hop_tpu(cu, cv, dims, pen,
                              interpret=(impl == "pallas_interpret"))
     from repro.kernels.hop_dist.ref import torus_hop_pairs_ref
-    return torus_hop_pairs_ref(cu, cv, dims)
+    return torus_hop_pairs_ref(cu, cv, dims, pen)
 
 
 def fattree_hop_pairs(cu, cv, impl: str = "auto"):

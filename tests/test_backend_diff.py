@@ -121,6 +121,37 @@ def test_fattree_lazy_refine_identical(health):
     np.testing.assert_allclose(hb, hb_ref, rtol=RTOL)
 
 
+@pytest.mark.parametrize("dims", [(4, 4, 4), (4, 3, 5, 2)])
+def test_faulty_lazy_torus_places_on_device(dims, monkeypatch):
+    """A faulty torus above the lazy threshold is a device path: tofa's
+    refines and scores run the jitted kernels with the penalised torus
+    metric in-kernel, nothing falls back to NumPy, and at float64 the
+    placement is the NumPy backend's own."""
+    from repro.core import mapping_jax
+
+    topo = TorusTopology(dims)
+    req = _request(topo, 24, faulty=True)
+    calls = []
+    real = mapping_jax.refine_many
+
+    def counted(*args, **kw):
+        calls.append(1)
+        return real(*args, **kw)
+
+    ref = PlacementEngine(backend="numpy", lazy_threshold=0).place(
+        req, policy="tofa", rng=np.random.default_rng(0))
+    monkeypatch.setattr(mapping_jax, "refine_many", counted)
+    engine = PlacementEngine(backend="jax", lazy_threshold=0)
+    with backend.use("jax") as be:
+        before = be.stats["numpy_fallbacks"]
+        out = engine.place(req, policy="tofa", rng=np.random.default_rng(0))
+        assert be.stats["numpy_fallbacks"] == before
+        assert be.dtype == "float64"
+    assert calls
+    np.testing.assert_array_equal(out.placement, ref.placement)
+    assert out.hop_bytes_fault_weighted == ref.hop_bytes_fault_weighted
+
+
 @pytest.mark.parametrize("host_name,topo", _hosts())
 def test_select_nodes_identical(host_name, topo):
     W = _weights(topo, faulty=True)
@@ -171,8 +202,9 @@ def test_policy_placements_identical(host_name, topo, faulty, policy):
 def test_numpy_fallbacks_counted(case):
     """Calls the jitted kernels cannot serve run the NumPy kernels, and
     each one is counted: a guest outside the symmetric CommGraph
-    convention, and a faulty torus above the lazy threshold (its lazy
-    distance has no implicit spec)."""
+    convention, and a faulty torus with a straggler above the lazy
+    threshold (stragglers price links by slowdown, so its lazy distance
+    has no implicit spec)."""
     from repro.core import mapping_jax
 
     topo = TorusTopology((4, 4, 4))
@@ -187,7 +219,10 @@ def test_numpy_fallbacks_counted(case):
                 ref = mapping.hop_bytes_batch(G, topo.hop_matrix(), P)
             np.testing.assert_array_equal(out, ref)
         else:
-            req = _request(topo, 24, faulty=True)
+            faulty = _request(topo, 24, faulty=True)
+            req = PlacementRequest(comm=faulty.comm, topology=topo,
+                                   p_f=faulty.route_p_f(),
+                                   straggler=np.full(topo.n_nodes, 0.5))
             engine = PlacementEngine(lazy_threshold=32)
             assert not mapping_jax.lazy_supported(
                 engine._weights_for(topo, req, req.route_p_f()))

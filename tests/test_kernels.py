@@ -421,3 +421,63 @@ def test_torus_hop_property(dx, dy, dz, seed):
     assert (h <= sum(d // 2 for d in dims)).all()             # diameter
     np.testing.assert_array_equal(h, torus_hop_np(cv, cu, dims))  # symmetry
     assert (torus_hop_np(cu, cu, dims) == 0).all()            # identity
+
+
+# ------------------------------------------------- penalised torus metric
+def _scalar_route_weights(topo, penal):
+    """Eq. (1) by one scalar walk of ``route_nodes`` per pair: hops plus
+    100 per link with a penalised endpoint, each link once."""
+    n = topo.n_nodes
+    out = np.zeros((n, n))
+    for u in range(n):
+        for v in range(n):
+            nodes = topo.route_nodes(u, v)
+            bad = sum(1 for a, b in zip(nodes[:-1], nodes[1:])
+                      if penal[a] or penal[b])
+            out[u, v] = (len(nodes) - 1) + 100.0 * bad
+    return out
+
+
+@pytest.mark.parametrize("dims,faulty", [
+    # adjacent faults along every dimension, the extent-2 ring included
+    ((4, 3, 5, 2), [0, 1, 2, 10, 30, 37, 119]),
+    # a 2x2 square of faults (links between two faulty nodes), extent 3
+    ((3, 4, 3), [0, 1, 3, 4, 13, 35]),
+    ((3, 4, 3), [17]),                       # one fault: F = 1, no padding
+    ((4, 3, 5, 2), [5, 6]),                  # two adjacent faults, F = 2
+], ids=["4x3x5x2-adjacent", "3x4x3-square", "3x4x3-one", "4x3x5x2-pair"])
+def test_penalised_torus_hop_matches_dense_and_scalar_walk(dims, faulty):
+    """Kernel (interpret), jnp reference (all-pairs, elementwise and row
+    forms), the lazy adapter and the dense ``weight_matrix`` all equal
+    the scalar route walk, over all pairs: faults on route endpoints and
+    u == v included."""
+    from repro.core.topology import TorusTopology
+    from repro.kernels.hop_dist.kernel import torus_hop_tpu
+    from repro.kernels.hop_dist.ops import torus_hop_pairs
+    from repro.kernels.hop_dist.ref import (torus_hop_elems_ref,
+                                            torus_hop_pairs_ref)
+
+    topo = TorusTopology(dims)
+    n = topo.n_nodes
+    p_f = np.zeros(n)
+    p_f[faulty] = 0.02
+    want = _scalar_route_weights(topo, p_f > 0)
+    np.testing.assert_array_equal(topo.weight_matrix(p_f), want)
+    lazy = topo.lazy_distance(p_f)
+    u, v = np.meshgrid(np.arange(n), np.arange(n), indexing="ij")
+    np.testing.assert_array_equal(lazy[u, v], want)
+    spec = lazy.implicit
+    f_pad = 1 << (len(faulty) - 1).bit_length()
+    assert spec.penalised.shape == (len(dims), f_pad)
+    c, pen = jnp.asarray(spec.coords), jnp.asarray(spec.penalised)
+    got = {
+        "kernel": torus_hop_tpu(c, c, dims, pen, interpret=True),
+        "pairs_ref": torus_hop_pairs_ref(c, c, dims, pen),
+        "auto": torus_hop_pairs(c, c, dims, pen),
+        "elems_ref": torus_hop_elems_ref(c[u.ravel()], c[v.ravel()], dims,
+                                         pen).reshape(n, n),
+        "rows_ref": jnp.stack([torus_hop_elems_ref(c[i], c, dims, pen)
+                               for i in range(n)]),
+    }
+    for name, value in got.items():
+        np.testing.assert_array_equal(np.asarray(value), want, err_msg=name)

@@ -85,12 +85,25 @@ def test_lazy_never_silently_densifies():
 
 
 def test_implicit_spec_only_when_uniform():
+    """The torus exposes its in-kernel spec in every health state whose
+    links are priced uniformly by hops and the fault term: healthy (an
+    empty penalised table) or faulty (the penalised coordinates, padded
+    to a power of two with -1).  Stragglers price links by slowdown, so
+    a straggling torus has no spec."""
     topo = TorusTopology((4, 4, 4))
-    assert topo.lazy_distance().implicit is not None
-    assert topo.lazy_distance(_faults(64, 3)).implicit is None
+    healthy = topo.lazy_distance().implicit
+    assert healthy is not None and healthy.penalised.shape == (3, 0)
+    p_f = _faults(64, 3)
+    faulty = topo.lazy_distance(p_f).implicit
+    assert faulty is not None and faulty.penalised.shape == (3, 4)
+    coords = topo.coords_array()
+    np.testing.assert_array_equal(faulty.penalised[:, :3],
+                                  coords[p_f > 0].T)
+    assert (faulty.penalised[:, 3] == -1).all()
     s = np.zeros(64)
     s[5] = 1.0
     assert topo.lazy_distance(straggler=s).implicit is None
+    assert topo.lazy_distance(p_f, straggler=s).implicit is None
     spec = topo.lazy_distance(p_f=np.zeros(64)).implicit
     assert spec is not None and spec.dims == (4, 4, 4)
 
@@ -292,3 +305,32 @@ def test_numpy_lazy_refine_matches_dense():
                                mapping.hop_bytes_batch(G, Dd, P), rtol=1e-12)
     np.testing.assert_array_equal(mapping.refine_batch(G, Dl, P),
                                   mapping.refine_batch(G, Dd, P))
+
+
+@pytest.mark.parametrize("dims", [(4, 3, 5, 2), (3, 4, 3)])
+def test_torus_route_extra_vectorised_equals_scalar_walk(dims):
+    """The lazy adapter prices all flagged pairs of a request in one
+    vectorised walk, ``==`` to a scalar walk of ``route_nodes`` per pair,
+    under faults (adjacent ones included) and stragglers."""
+    from repro.core.topology import FAULT_PENALTY
+    topo = TorusTopology(dims)
+    n = topo.n_nodes
+    p_f = np.zeros(n)
+    p_f[[0, 1, 3, 4, 17]] = 0.02
+    slow = np.zeros(n)
+    slow[[2, 9, 30]] = [0.37, 1.25, 0.5]
+    for straggler in (None, slow):
+        lazy = topo.lazy_distance(p_f, c=2.0, straggler=straggler)
+        u, v = (a.ravel() for a in np.meshgrid(np.arange(n), np.arange(n),
+                                               indexing="ij"))
+        got = lazy._route_extra(u, v)
+        s = np.zeros(n) if straggler is None else straggler
+        want = np.zeros(n * n)
+        for i, (a0, b0) in enumerate(zip(u, v)):
+            nodes = topo.route_nodes(int(a0), int(b0))
+            for a, b in zip(nodes[:-1], nodes[1:]):
+                if p_f[a] > 0 or p_f[b] > 0:
+                    want[i] += 2.0 * FAULT_PENALTY
+                elif s[a] > 0 or s[b] > 0:
+                    want[i] += 2.0 * max(s[a], s[b])
+        assert (got == want).all()

@@ -10,7 +10,8 @@ undo.  The child script exercises:
   single-device vmap path, ``REPRO_JAX_DEVICES`` caps it;
 * sharded ``refine_many`` (shard_map over the candidate axis) returns
   placements **bit-identical** to the single-device vmap dispatch — on
-  dense, implicit-torus, and implicit-fat-tree distances — including
+  dense, implicit-torus, implicit-fat-tree and implicit faulty-torus
+  distances — including
   ragged stacks that need edge-padding to a device multiple;
 * the ``sharded_dispatches`` stat increments only on the sharded path.
 """
@@ -47,10 +48,13 @@ torus = TorusTopology((4, 4, 4))
 ft = FatTreeTopology(8)
 p_f = np.zeros(ft.n_nodes)
 p_f[rng.choice(ft.n_nodes, 6, replace=False)] = 0.1
+p_t = np.zeros(torus.n_nodes)
+p_t[[0, 1, 5, 21, 42]] = 0.02          # 0 and 1 adjacent
 cases = [
     ("dense", torus.hop_matrix(), torus.n_nodes),
     ("implicit-torus", torus.lazy_distance(), torus.n_nodes),
     ("implicit-fattree", ft.lazy_distance(p_f, c=2.0), ft.n_nodes),
+    ("implicit-faulty-torus", torus.lazy_distance(p_t), torus.n_nodes),
 ]
 for b in (3, 8, 16):     # ragged (pad to device multiple), 1/lane, 2/lane
     for name, D, n_nodes in cases:

@@ -77,8 +77,24 @@ def test_hop_dist_compiles(one_chip, metric):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("n_pen", [1, 16, 32])
+def test_penalised_torus_hop_compiles(one_chip, n_pen):
+    """The faulty torus's kernel at Titan's shape (25x16x24x2): the
+    penalised table and its neighbour table in SMEM, the Pallas call
+    named ``torus_hop``."""
+    from repro.kernels.hop_dist.kernel import torus_hop_tpu
+
+    def fn(cu, cv, pen):
+        return torus_hop_tpu(cu, cv, (25, 16, 24, 2), pen)
+
+    text = _compiled_text(fn, _shape(one_chip, (512, 4), F32),
+                          _shape(one_chip, (512, 4), F32),
+                          _shape(one_chip, (4, n_pen), F32))
+    assert "tpu_custom_call" in text and "torus_hop" in text
+
+
 @pytest.mark.parametrize("case", ["dense", "implicit-torus",
-                                  "implicit-fattree"])
+                                  "implicit-fattree", "implicit-faulty-torus"])
 def test_refine_step_compiles_with_pallas(one_chip, monkeypatch, case):
     """One whole jitted candidate-stack refine, with ``impl="auto"``
     steered to the Pallas kernels as it resolves on a TPU host: the dense
@@ -93,7 +109,11 @@ def test_refine_step_compiles_with_pallas(one_chip, monkeypatch, case):
         Ds = _shape(one_chip, (512, 512), F32)
     elif case == "implicit-torus":
         dense, dims = False, (16, 16, 32)
-        Ds = _shape(one_chip, (8192, 3), F32)
+        Ds = (_shape(one_chip, (8192, 3), F32), _shape(one_chip, (3, 0), F32))
+    elif case == "implicit-faulty-torus":
+        dense, dims = False, (25, 16, 24, 2)
+        Ds = (_shape(one_chip, (19200, 4), F32),
+              _shape(one_chip, (4, 16), F32))
     else:
         dense, dims = False, ("fattree",)
         Ds = (_shape(one_chip, (5488, 3), F32),
