@@ -17,6 +17,11 @@ deployment size, with the jax backend pinned to one device:
      in-kernel by the Pallas ``torus_hop`` kernel.
   d. NPB-DT-like guest (512 ranks) onto a k=28 fat-tree (5488 hosts) with
      faulty hosts: the Pallas ``fattree_hop`` kernel.
+  e. NPB-DT-like and LAMMPS-like guests (512 ranks) onto a k=48 fat-tree
+     (27,648 hosts) and onto Titan's 25x16x24x2 torus (19,200 nodes), 16
+     faulty nodes each: every lane of each multilevel level's one-dispatch
+     sub-problem refine (``mapping_jax.refine_subproblems``) must equal
+     the sub-problem's own ``refine_many`` dispatch, at the chip's float32.
 
 Phases b-d are checked against the numpy backend's placement of the same
 request: the chip's placement must be valid and its hop-bytes, evaluated
@@ -35,7 +40,7 @@ The last line of standard output is one JSON object,
 printed only when every check passed.  Without a TPU the script exits
 non-zero before any phase runs.
 
-    python chip_smoke.py              # one chip, phases a-d
+    python chip_smoke.py              # one chip, phases a-e
     python chip_smoke.py --chips 4    # sharded refine on four chips
 """
 from __future__ import annotations
@@ -53,6 +58,8 @@ ROOT = Path(__file__).resolve().parent
 MAX_HOP_BYTES_EXCESS = 0.01     # chip hop-bytes <= (1 + this) * numpy's
 N_FAULTY = 8                    # faulty nodes of phases b and d
 FAULT_P = 0.05                  # their outage belief
+N_FAULTY_E = 16                 # faulty nodes of phase e, at outage
+FAULT_P_E = 0.02                # belief 0.02
 
 
 class SmokeFailure(RuntimeError):
@@ -91,7 +98,8 @@ class _RefineRecorder:
     def __enter__(self):
         def recorded(G_w, D, placements, *args, **kw):
             self.calls += 1
-            self.last = (G_w, D, placements, args, kw)
+            if kw.get("lane") is None:       # a dispatch of its own
+                self.last = (G_w, D, placements, args, kw)
             return self._orig(G_w, D, placements, *args, **kw)
         self._mj.refine_many = recorded
         return self
@@ -206,11 +214,12 @@ def check_service(row: dict) -> list[str]:
 
 
 # ------------------------------------------------------------ phases b-d
-def _faulty_state(n_nodes: int, n_faulty: int, seed: int):
+def _faulty_state(n_nodes: int, n_faulty: int, seed: int,
+                  p: float = FAULT_P):
     from repro.core.state import ClusterState
     p_f = np.zeros(n_nodes)
     rng = np.random.default_rng(seed)
-    p_f[rng.choice(n_nodes, n_faulty, replace=False)] = FAULT_P
+    p_f[rng.choice(n_nodes, n_faulty, replace=False)] = p
     return ClusterState.healthy(n_nodes).with_outage(p_f)
 
 
@@ -292,8 +301,85 @@ def check_placement(row: dict, want_pallas: bool = True) -> list[str]:
     return out
 
 
+# ---------------------------------------------------------------- phase e
+def run_subproblem_lanes(topo, workload, state=None,
+                         lazy_threshold=None) -> dict:
+    """One tofa placement of ``workload`` with the jax backend; then each
+    multilevel level's sub-problems, as its one dispatch was given them,
+    are refined again by one ``refine_many`` dispatch each, and every
+    lane is compared with its own dispatch."""
+    from repro.core import backend, mapping_jax
+    from repro.core.engine import PlacementEngine, PlacementRequest
+    from repro.core.state import ClusterState
+
+    state = state or ClusterState.healthy(topo.n_nodes)
+    req = PlacementRequest(comm=workload.comm, topology=topo, state=state)
+    levels = []
+    orig = mapping_jax.refine_subproblems
+
+    def recorded(G_w, D, groups, placements, *args, **kw):
+        inputs = [np.array(p) for p in placements]
+        out = orig(G_w, D, groups, placements, *args, **kw)
+        levels.append((G_w, D, list(groups), inputs, out))
+        return out
+
+    mapping_jax.refine_subproblems = recorded
+    try:
+        t0 = time.perf_counter()
+        PlacementEngine(backend="jax", lazy_threshold=lazy_threshold).place(
+            req, policy="tofa", rng=np.random.default_rng(0))
+        place_s = time.perf_counter() - t0
+    finally:
+        mapping_jax.refine_subproblems = orig
+    lanes = equal = moved = 0
+    with backend.use("jax"):
+        for G_w, D, groups, inputs, out in levels:
+            for F, p, got in zip(groups, inputs, out):
+                alone = mapping_jax.refine_many(G_w[np.ix_(F, F)], D,
+                                                p[None])[0]
+                lanes += 1
+                equal += bool(np.array_equal(got, alone))
+                moved += not np.array_equal(got, p)
+    return {"nodes": topo.n_nodes, "ranks": req.n_procs,
+            "faulty": int((state.p_f > 0).sum()),
+            "dtype": backend.get_backend("jax").dtype,
+            "place_s": place_s, "levels": len(levels),
+            "widths": sorted({len(F) for _, _, g, _, _ in levels
+                              for F in g}),
+            "lanes": lanes, "lanes_equal": equal, "lanes_moved": moved}
+
+
+def check_subproblem_lanes(row: dict) -> list[str]:
+    out = []
+    if not row["lanes"]:
+        out.append("no multilevel sub-problem was batched")
+    if row["lanes_equal"] != row["lanes"]:
+        out.append(f"{row['lanes'] - row['lanes_equal']} of {row['lanes']} "
+                   f"batched lanes differ from their own dispatch")
+    return out
+
+
+def subproblem_phases() -> dict:
+    """Phase e: the benchmark's 512-rank guests on its two large
+    fabrics."""
+    from repro.core.fattree import FatTreeTopology
+    from repro.core.topology import TorusTopology
+    from repro.workloads.patterns import lammps_like, npb_dt_like
+    phases = {}
+    for fabric, topo in (("fattree-k48", FatTreeTopology(48)),
+                         ("titan", TorusTopology((25, 16, 24, 2)))):
+        state = _faulty_state(topo.n_nodes, N_FAULTY_E, seed=3,
+                              p=FAULT_P_E)
+        for guest, wl in (("npb-dt", npb_dt_like(512)),
+                          ("lammps", lammps_like(512))):
+            name = f"e-lanes-{fabric}-{guest}"
+            phases[name] = run_subproblem_lanes(topo, wl, state)
+            _report(name, phases[name])
+    return phases
+
+
 def one_chip_phases() -> dict:
-    """Phases a-d at the paper's deployment size."""
+    """Phases a-e at the paper's deployment size."""
     from repro.core.fattree import FatTreeTopology
     from repro.core.topology import TorusTopology
     from repro.workloads.patterns import (alltoall_heavy, lammps_like,
@@ -311,14 +397,17 @@ def one_chip_phases() -> dict:
              _faulty_state(ft.n_nodes, N_FAULTY, seed=2))):
         phases[name] = run_placement(topo, wl, state)
         _report(name, phases[name])
+    phases.update(subproblem_phases())
     return phases
 
 
 def check_one_chip(phases: dict) -> list[str]:
     out = [f"a-service: {m}" for m in check_service(phases["a-service"])]
     for name, row in phases.items():
+        check = (check_subproblem_lanes if name.startswith("e-")
+                 else check_placement)
         if name != "a-service":
-            out += [f"{name}: {m}" for m in check_placement(row)]
+            out += [f"{name}: {m}" for m in check(row)]
     return out
 
 
@@ -392,7 +481,7 @@ def check_sharded(rows: dict, n_dev: int) -> list[str]:
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
-                    help="1: phases a-d on one device; 4: only the "
+                    help="1: phases a-e on one device; 4: only the "
                          "sharded refine, against one device")
     args = ap.parse_args(argv)
     if args.chips == 1:

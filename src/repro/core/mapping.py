@@ -438,6 +438,30 @@ def refine_batch(G_w: np.ndarray, D: np.ndarray, placements: np.ndarray,
     return np.stack([refiner(G_w, D, p) for p in P])
 
 
+def refine_subproblems(G_w: np.ndarray, D, groups,
+                       placements) -> list[np.ndarray]:
+    """Refine disjoint sub-problems of one guest: ``placements[s]`` places
+    processes ``groups[s]``, and is refined as
+    ``_pairwise_refine(G_w[np.ix_(F, F)], D, placements[s])`` would
+    refine it alone.
+
+    When the vectorized kernel is installed and the jax backend serves
+    the guest and ``D``, every sub-problem refines in one device
+    dispatch (:func:`repro.core.mapping_jax.refine_subproblems`, which
+    still asks ``refine_many`` for each sub-problem's result);
+    otherwise (numpy backend, ``use_reference_impl``, an asymmetric
+    guest, a straggling torus) this loops the module-global
+    ``_pairwise_refine`` over them in order.
+    """
+    refiner = globals()["_pairwise_refine"]
+    if refiner is _VECTORIZED_IMPL.get("_pairwise_refine"):
+        jx = _jax_kernels(G_w, D)
+        if jx is not None:
+            return jx.refine_subproblems(G_w, D, groups, placements)
+    return [refiner(G_w[np.ix_(F, F)], D, p)
+            for F, p in zip(groups, placements)]
+
+
 def best_map(G_w, node_sets, coords, D, rng) -> np.ndarray:
     """Map onto each candidate node subset, keep the lowest hop-bytes.
 

@@ -201,31 +201,44 @@ def _dist_fns(Ds, dims, scale):
 
 
 def _sparse_rows(G_w: np.ndarray) -> tuple[np.ndarray, np.ndarray, int]:
-    """CSR-padded rows of the (diag-zeroed) guest: (idx, val, k_pad).
-
-    Rows are padded to a power-of-two width with (index 0, weight 0.0)
-    entries — gathers against them multiply by exactly 0.0, so padding
-    never changes a result.
-    """
+    """CSR-padded rows of the (diag-zeroed) guest: (idx, val, k_pad),
+    as :func:`_csr_rows` lays them out for one lane."""
     def build():
         G = np.asarray(G_w, dtype=np.float64)
         if np.count_nonzero(np.diagonal(G)):
             G = G.copy()
             np.fill_diagonal(G, 0.0)
         n = G.shape[0]
-        nnz = (G != 0.0).sum(axis=1)
-        # multiple-of-4 width: tight enough that padded gathers stay
-        # cheap, coarse enough that compile-cache keys rarely vary
-        k_true = max(1, int(nnz.max()) if n else 1)
-        k = min(_pow2(n), (k_true + 3) & ~3)
-        idx = np.zeros((n, k), dtype=np.int32)
-        val = np.zeros((n, k), dtype=np.float64)
-        for r in range(n):
-            cols = np.flatnonzero(G[r])
-            idx[r, :len(cols)] = cols
-            val[r, :len(cols)] = G[r, cols]
-        return idx, val, k, G
+        r, c = np.nonzero(G)
+        idx, val = _csr_rows(np.zeros(len(r), dtype=np.int64), r, c,
+                             G[r, c], 1, n)
+        return idx[0], val[0], idx.shape[2], G
     return _SPARSE_CACHE.get(G_w, build)
+
+
+def _csr_rows(lane, row, col, val, S: int, n_rows: int):
+    """CSR-padded rows of ``S`` guests from their nonzeros, given as
+    (lane, row, column, weight) entries: ``(idx, val)`` of shape
+    ``(S, n_rows, k)``, each row listing its columns in ascending order.
+
+    Rows are padded with (index 0, weight 0.0) entries — gathers against
+    them multiply by exactly 0.0, so padding never changes a result — to
+    one width ``k``: the widest row rounded up to a multiple of 4 (tight
+    enough that padded gathers stay cheap, coarse enough that
+    compile-cache keys rarely vary), at most the power of two of
+    ``n_rows``."""
+    order = np.lexsort((col, row, lane))
+    lane, row, col, val = lane[order], row[order], col[order], val[order]
+    slot = lane * n_rows + row
+    nnz = np.bincount(slot, minlength=S * n_rows)
+    pos = np.arange(len(slot)) - (np.cumsum(nnz) - nnz)[slot]
+    k_true = max(1, int(nnz.max(initial=0)))
+    k = min(_pow2(n_rows), (k_true + 3) & ~3)
+    idx = np.zeros((S, n_rows, k), dtype=np.int32)
+    vals = np.zeros((S, n_rows, k), dtype=np.float64)
+    idx[lane, row, pos] = col
+    vals[lane, row, pos] = val
+    return idx, vals
 
 
 def _sym_host(D: np.ndarray) -> np.ndarray:
@@ -501,7 +514,7 @@ def _device_distances(D, be):
 
 def refine_many(G_w: np.ndarray, D: np.ndarray, placements: np.ndarray,
                 max_passes: int = 3, movers: int = 64,
-                extra_passes: int = 13) -> np.ndarray:
+                extra_passes: int = 13, *, lane=None) -> np.ndarray:
     """Batched ``_pairwise_refine``: (B, n) placements in one dispatch.
 
     With multiple visible devices (``backend.JaxBackend.device_count``
@@ -515,7 +528,18 @@ def refine_many(G_w: np.ndarray, D: np.ndarray, placements: np.ndarray,
     split into ``refine.prepare`` (host work up to the enqueue: padding,
     sparse rows, device copies, the launch) and ``refine.wait`` (the
     device's work and the copy back to the host).
+
+    ``lane=(batch, s)`` asks for sub-problem ``s`` of a
+    :class:`SubproblemBatch` (``G_w`` and ``placements`` are then that
+    sub-problem's guest and its one-row placement): the batch's one
+    dispatch, under span ``refine.subproblems`` (args ``S`` sub-problems,
+    ``n`` padded width) with the same ``refine.prepare`` /
+    ``refine.wait`` split, is made by the first of its lanes asked for.
     """
+    if lane is not None:
+        batch, s = lane
+        out = batch.result(s, placements)
+        return out[None] if np.asarray(placements).ndim == 2 else out
     be = _be()
     B, n = np.atleast_2d(placements).shape
     with _spans.span("refine", B=B, n=n):
@@ -593,6 +617,159 @@ def pairwise_refine(G_w: np.ndarray, D: np.ndarray, placement: np.ndarray,
     return refine_many(G_w, D, np.asarray(placement)[None, :],
                        max_passes=max_passes, movers=movers,
                        extra_passes=extra_passes)[0]
+
+
+# --------------------------------------------------------------------------
+# disjoint sub-problems of one guest (a multilevel uncoarsening level)
+# --------------------------------------------------------------------------
+
+_COO_CACHE = _IdLRU()
+
+
+def _guest_coo(G_w: np.ndarray):
+    """Off-diagonal nonzeros of the guest, row-major: (rows, cols, vals).
+    Cached by identity — every candidate of a placement refines its
+    levels against the same guest object."""
+    def build():
+        G = np.asarray(G_w, dtype=np.float64)
+        r, c = np.nonzero(G)
+        off = r != c
+        r, c = r[off], c[off]
+        return r, c, G[r, c]
+    return _COO_CACHE.get(G_w, build)
+
+
+@functools.lru_cache(maxsize=32)
+def _refine_jit_subproblems(movers: int, total_passes: int, dims=None,
+                            scale: float = 1.0):
+    """Sparse refine of a stack of *different* guests: placement, CSR
+    rows and valid count per lane, the distance operand shared."""
+    fn = functools.partial(_refine_one, movers=movers,
+                           total_passes=total_passes, dense=False,
+                           dims=dims, scale=scale)
+    return jax.jit(jax.vmap(fn, in_axes=(0, 0, 0, None, None, 0)))
+
+
+class SubproblemBatch:
+    """Disjoint sub-problems of one guest, refined in one device dispatch.
+
+    Sub-problem ``s`` is the guest restricted to processes ``groups[s]``
+    (``G_w[np.ix_(F, F)]``), placed at ``placements[s]``.  Its result is
+    asked for through :func:`refine_many` with ``lane=(batch, s)``; the
+    first such call refines every sub-problem at once, as the lanes of
+    one vmapped program, and later calls return their lane.  Each lane
+    is padded to one width (the power of two of the largest sub-problem)
+    and one sparse row width, and the stack to a power of two by
+    repeating its last lane (sliced off), so a few compiled programs
+    serve every level.  Padding rows are masked, padded movers are
+    identity swaps, and a converged lane of the vmapped loop is kept as
+    it is, so each lane accepts the swaps it would accept alone.  Runs
+    on one device.
+
+    ``dense`` is whether some sub-problem, refined alone, would take the
+    dense ``swap_select`` branch; such a batch cannot run, and each
+    sub-problem takes its own :func:`refine_many` dispatch instead.
+    """
+
+    def __init__(self, G_w: np.ndarray, D, groups, placements,
+                 max_passes: int = 3, movers: int = 64,
+                 extra_passes: int = 13):
+        self.D = D
+        self.inputs = [np.asarray(p, dtype=np.int64) for p in placements]
+        self.sizes = np.array([len(F) for F in groups], dtype=np.int64)
+        self.n_pad = _pow2(int(self.sizes.max()))
+        self._passes = (max_passes, movers, extra_passes)
+        S = len(groups)
+        flat = np.concatenate(groups).astype(np.int64)
+        lanes = np.repeat(np.arange(S), self.sizes)
+        lane_of = np.full(G_w.shape[0], -1, dtype=np.int64)
+        lane_of[flat] = lanes
+        if not np.array_equal(lane_of[flat], lanes):
+            raise ValueError("sub-problem process groups must be disjoint")
+        loc = np.zeros(G_w.shape[0], dtype=np.int64)
+        loc[flat] = (np.arange(len(flat))
+                     - np.repeat(np.cumsum(self.sizes) - self.sizes,
+                                 self.sizes))
+        r, c, v = _guest_coo(G_w)
+        keep = (lane_of[r] >= 0) & (lane_of[r] == lane_of[c])
+        self._entries = (lane_of[r[keep]], loc[r[keep]], loc[c[keep]],
+                         v[keep])
+        # the row width and dense test each sub-problem would get alone
+        lane, row = self._entries[:2]
+        widest = np.bincount(lane * self.n_pad + row,
+                             minlength=S * self.n_pad).reshape(
+                                 S, self.n_pad).max(axis=1, initial=0)
+        own_pad = np.array([_pow2(n) for n in self.sizes])
+        k_own = np.minimum(own_pad, (np.maximum(1, widest) + 3) & ~3)
+        self.dense = bool((k_own > np.maximum(8, own_pad // 2)).any())
+        self._out = None
+
+    def program(self):
+        """``(jitted refine, operands)``: the one dispatch of this batch.
+        Call inside the active backend's
+        :meth:`~repro.core.backend.JaxBackend.scope`."""
+        if self.dense:
+            raise ValueError("a sub-problem takes the dense branch alone")
+        be = _be()
+        S, n_pad = len(self.sizes), self.n_pad
+        lanes = np.repeat(np.arange(S), self.sizes)
+        local = np.concatenate([np.arange(n) for n in self.sizes])
+        P = np.zeros((S, n_pad), dtype=np.int32)
+        P[lanes, local] = np.concatenate(self.inputs)
+        idx, val = _csr_rows(*self._entries, S, n_pad)
+        n_valid = self.sizes.astype(np.int32)
+        S_pad = _pow2(S)
+        if S_pad != S:
+            P, idx, val, n_valid = (
+                np.pad(a, ((0, S_pad - S),) + ((0, 0),) * (a.ndim - 1),
+                       mode="edge") for a in (P, idx, val, n_valid))
+        Ds, dims, scale = _device_distances(self.D, be)
+        max_passes, movers, extra_passes = self._passes
+        run = _refine_jit_subproblems(min(movers, n_pad),
+                                      max_passes + extra_passes, dims, scale)
+        P, idx, val, n_valid = jax.device_put(
+            (P, idx, val.astype(be.np_dtype), n_valid))
+        return run, (P, idx, val, jnp.zeros((1, 1), be.np_dtype), Ds,
+                     n_valid)
+
+    def result(self, s: int, placement: np.ndarray) -> np.ndarray:
+        """Lane ``s`` refined, dispatching the batch on first demand;
+        ``placement`` must be the lane's input."""
+        if not np.array_equal(np.ravel(placement), self.inputs[s]):
+            raise ValueError(f"lane {s} was batched with another placement")
+        if self._out is None:
+            be = _be()
+            with _spans.span("refine.subproblems", S=len(self.sizes),
+                             n=self.n_pad):
+                with be.scope(), _spans.span("refine.prepare"):
+                    run, args = self.program()
+                    out = run(*args)
+                with _spans.span("refine.wait"):
+                    self._out = np.asarray(out).astype(np.int64)
+        return self._out[s, :self.sizes[s]].copy()
+
+
+def refine_subproblems(G_w: np.ndarray, D, groups, placements,
+                       max_passes: int = 3, movers: int = 64,
+                       extra_passes: int = 13) -> list[np.ndarray]:
+    """Refine disjoint sub-problems of one guest, all in one dispatch.
+
+    Each sub-problem goes through :func:`refine_many` on its own guest
+    ``G_w[np.ix_(F, F)]`` and its one-row placement, so a wrapper of
+    ``refine_many`` sees every sub-problem as before, and each result is
+    what ``refine_many`` would return for it alone; the calls share one
+    :class:`SubproblemBatch`, which makes the only device dispatch.
+    Where a sub-problem alone would take the dense branch, each call is
+    its own dispatch instead.
+    """
+    if not len(groups):
+        return []
+    batch = SubproblemBatch(G_w, D, groups, placements, max_passes, movers,
+                            extra_passes)
+    return [refine_many(G_w[np.ix_(F, F)], D, p[None], max_passes, movers,
+                        extra_passes,
+                        lane=None if batch.dense else (batch, s))[0]
+            for s, (F, p) in enumerate(zip(groups, batch.inputs))]
 
 
 # --------------------------------------------------------------------------

@@ -22,9 +22,10 @@ Assignment"):
    size in nodes.
 3. **Uncoarsen**: expand each super-vertex into its children and
    recursively map them *within the parent's chunk*, then run per-level
-   local delta-swap refinement (:func:`mapping._pairwise_refine` on the
-   chunk subproblem) followed by a global
-   :func:`mapping.refine_batch` pass over the final candidates.
+   local delta-swap refinement (:func:`mapping._pairwise_refine` on each
+   chunk subproblem; all of one level's subproblems at once through
+   :func:`mapping.refine_subproblems`, finest level first) followed by
+   a global :func:`mapping.refine_batch` pass over the final candidates.
 
 Mapping work per level is proportional to the level's vertex count, so
 total work is a geometric series dominated by the finest level — the
@@ -187,6 +188,9 @@ def multilevel_map(G_w: np.ndarray, nodes: np.ndarray, coords: np.ndarray,
                                  refine=refine)
 
     placement = np.full(n, -1, dtype=np.int64)
+    # each level's refine sub-problems (process sets), recorded by the
+    # descent and refined after it, level by level from the finest
+    subproblems: list[list[np.ndarray]] = [[] for _ in levels]
 
     def descend(li: int, members: np.ndarray, chunk: np.ndarray) -> None:
         """Map ``members`` (vertices of levels[li].G) onto ``chunk``."""
@@ -205,16 +209,15 @@ def multilevel_map(G_w: np.ndarray, nodes: np.ndarray, coords: np.ndarray,
         for local, m in enumerate(members):
             descend(li - 1, kids[int(m)], sub_chunks[local])
         # local uncoarsening refinement: the original processes under
-        # ``members`` now occupy ``chunk``; polish their arrangement
-        # against the *global* metric restricted to this subproblem
+        # ``members`` occupy ``chunk``; their arrangement is polished,
+        # after the descent, against the *global* metric restricted to
+        # this subproblem
         if refine and D is not None:
             procs = _procs_by_level[li - 1]
             F = np.concatenate([procs[int(m)] for m in members]) \
                 if len(members) > 1 else procs[int(members[0])]
             if 4 <= len(F) <= _LOCAL_REFINE_MAX:
-                refiner = mapping.__dict__["_pairwise_refine"]
-                placement[F] = refiner(
-                    G_w[np.ix_(F, F)], D, placement[F])
+                subproblems[li].append(F)
 
     # children of a level-li coarse vertex (vertices of levels[li].G),
     # and the original processes each level-li vertex represents
@@ -229,6 +232,19 @@ def multilevel_map(G_w: np.ndarray, nodes: np.ndarray, coords: np.ndarray,
     top_kids = _children_by_level[-1]
     for v in range(Gc.shape[0]):
         descend(len(levels) - 1, top_kids[v], top_chunks[v])
+
+    # The subproblems of one level are disjoint in processes and nodes,
+    # each refine permutes only its own processes' nodes, and the
+    # descent never reads a placement, so refining each level in one
+    # batch after the whole descent gives every subproblem the input
+    # the depth-first order gave it: its descendants' refines are done,
+    # its ancestors' are not.
+    for groups in subproblems:
+        if groups:
+            refined = mapping.refine_subproblems(
+                G_w, D, groups, [placement[F] for F in groups])
+            for F, p in zip(groups, refined):
+                placement[F] = p
 
     assert (placement >= 0).all()
     if D is None:

@@ -64,3 +64,19 @@ def test_sharded_phase_checks():
     assert len(msgs) == 2
     assert any("differ" in m for m in msgs)
     assert any("did not increase" in m for m in msgs)
+
+
+def test_subproblem_lanes_phase_small():
+    """384-rank NPB-DT coarsens three times, so tofa's multilevel levels
+    batch their sub-problems; at float64 every lane equals its own
+    dispatch."""
+    topo = FatTreeTopology(12)
+    state = chip_smoke._faulty_state(topo.n_nodes, 12, seed=3,
+                                     p=chip_smoke.FAULT_P_E)
+    row = chip_smoke.run_subproblem_lanes(topo, npb_dt_like(384), state,
+                                          lazy_threshold=64)
+    assert chip_smoke.check_subproblem_lanes(row) == []
+    assert row["dtype"] == "float64"
+    assert row["levels"] >= 2 and row["lanes_moved"] > 0
+    assert chip_smoke.check_subproblem_lanes(
+        dict(row, lanes_equal=row["lanes"] - 1)) != []

@@ -127,3 +127,28 @@ def test_refine_step_compiles_with_pallas(one_chip, monkeypatch, case):
         _shape(one_chip, (n, k), F32), _shape(one_chip, G_dense, F32), Ds,
         _shape(one_chip, (), I32)).compile().as_text()
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("case", ["implicit-fattree", "implicit-faulty-torus"])
+def test_subproblem_refine_compiles_with_pallas(one_chip, monkeypatch, case):
+    """One multilevel level's sub-problems as one program: a stack of 128
+    guests of up to 8 ranks, each lane with its own sparse rows and
+    valid count, the implicit metric shared, ``hop_dist`` in every lane."""
+    from repro.core import mapping_jax
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    S, n, k = 128, 8, 4
+    if case == "implicit-faulty-torus":
+        dims = (25, 16, 24, 2)
+        Ds = (_shape(one_chip, (19200, 4), F32),
+              _shape(one_chip, (4, 16), F32))
+    else:
+        dims = ("fattree",)
+        Ds = (_shape(one_chip, (27648, 3), F32),
+              _shape(one_chip, (27648,), F32))
+    run = mapping_jax._refine_jit_subproblems.__wrapped__(n, 16, dims, 1.0)
+    text = run.lower(
+        _shape(one_chip, (S, n), I32), _shape(one_chip, (S, n, k), I32),
+        _shape(one_chip, (S, n, k), F32), _shape(one_chip, (1, 1), F32), Ds,
+        _shape(one_chip, (S,), I32)).compile().as_text()
+    assert "tpu_custom_call" in text
