@@ -45,12 +45,13 @@ pinned to float64.  With the optional ``jax`` backend active
 jit-compiled kernels of :mod:`repro.core.mapping_jax` — decision-identical
 at float64 (bit-identical placements for the integer-weighted in-tree
 workloads), with all candidate refinements of one mapping call batched
-into a single device dispatch.  Asymmetric guest matrices (outside the
-CommGraph convention) and lazy distances without an implicit spec (a
-straggling torus) fall back to the NumPy kernels, counted in the backend's
-``stats["numpy_fallbacks"]``.  Inside
-``use_reference_impl`` the retained scalar loops always run, regardless
-of backend — they are the fixed baseline.
+into a single device dispatch.  :func:`_jax_kernels` makes every choice
+between the two: asymmetric guest matrices (outside the CommGraph
+convention) and lazy distances without an implicit spec (a straggling
+torus) fall back to the NumPy kernels, counted in the backend's
+``stats["numpy_fallbacks"]``, and inside ``use_reference_impl`` the
+retained scalar loops always run, regardless of backend — they are the
+fixed baseline.
 """
 from __future__ import annotations
 
@@ -62,22 +63,39 @@ from . import backend as _backend
 from .lazydist import is_lazy
 
 
-def _jax_kernels(G_w: np.ndarray | None = None, D=None):
-    """The jitted kernel module when the jax backend should serve this
-    call, else None (numpy path).  ``G_w`` adds the symmetric-guest
-    check for guest-dependent kernels; ``D`` adds the lazy-distance
-    check — a lazy adapter is served only when the backend can compute
-    its entries in-kernel (a fat-tree in any health state, a torus with
-    no stragglers, faulty or not), otherwise the NumPy kernels run
-    against the adapter's ``__getitem__``.  Each such turn-away is
-    counted in the jax backend's ``stats["numpy_fallbacks"]``."""
+# The refine's fixed shape on every backend: each pass tries the
+# REFINE_MOVERS costliest processes as movers, until a pass accepts no
+# swap or REFINE_PASSES passes have run.
+REFINE_MOVERS = 64
+REFINE_PASSES = 16
+# swap acceptance threshold; the device refine reads its own copy,
+# repro.kernels.swap_gain.ref.GAIN_EPS, which a NumPy-only install cannot
+# import (tests/test_mapping_diff.py pins the two together)
+GAIN_EPS = 1e-9
+_reference_mode = False     # True inside use_reference_impl()
+
+
+def _jax_kernels(op: str, G_w: np.ndarray | None = None, D=None):
+    """The jitted kernel module when the jax backend serves ``op``
+    (``"hop_bytes"``, ``"refine"``, ``"select_nodes"`` or
+    ``"greedy_placement"``) on these operands, else None (NumPy path).
+    The refine is turned away inside :func:`use_reference_impl`
+    (hop-bytes has no reference).  A lazy ``D`` is served only by
+    hop-bytes and the refine, which compute its entries in-kernel, and
+    only when it has an implicit spec (a fat-tree in any health state, a
+    torus with no stragglers); ``G_w``, where given, must be symmetric.
+    Those two turn-aways count in ``stats["numpy_fallbacks"]``."""
     be = _backend.active()
     if not getattr(be, "is_jax", False):
         return None
+    if op == "refine" and _reference_mode:
+        return None
+    lazy = D is not None and is_lazy(D)
+    if lazy and op not in ("hop_bytes", "refine"):
+        return None
     from . import mapping_jax
     if ((G_w is not None and not mapping_jax.guest_supported(G_w))
-            or (D is not None and is_lazy(D)
-                and not mapping_jax.lazy_supported(D))):
+            or (lazy and not mapping_jax.lazy_supported(D))):
         be.stats["numpy_fallbacks"] += 1
         return None
     return mapping_jax
@@ -94,9 +112,10 @@ def hop_bytes(G_v: np.ndarray, D: np.ndarray, placement: np.ndarray) -> float:
     entries) this equals sum over unordered pairs of bytes * distance; an
     asymmetric route-weight matrix D is implicitly symmetrised.
     """
-    jx = _jax_kernels(G_v, D)
+    jx = _jax_kernels("hop_bytes", G_v, D)
     if jx is not None:
-        return jx.hop_bytes(G_v, D, placement)
+        return float(jx.hop_bytes_batch(G_v, D,
+                                        np.asarray(placement)[None])[0])
     p = np.asarray(placement)
     return float(0.5 * (G_v * D[np.ix_(p, p)]).sum())
 
@@ -115,7 +134,7 @@ def hop_bytes_batch(
     P = np.asarray(placements)
     if P.ndim == 1:
         return np.array([hop_bytes(G_v, D, P)])
-    jx = _jax_kernels(G_v, D)
+    jx = _jax_kernels("hop_bytes", G_v, D)
     if jx is not None:
         return jx.hop_bytes_batch(G_v, D, P)
     k, n = P.shape
@@ -194,8 +213,7 @@ def bisect_graph(
     # part 0) is maintained incrementally across passes; each pass applies
     # one swap, so n bounds the useful pass count.
     int0 = W @ in0
-    max_passes = n if fm_passes is None else fm_passes
-    for _ in range(max_passes):
+    for _ in range(n if fm_passes is None else fm_passes):
         gain = np.where(in0, deg - 2.0 * int0, 2.0 * int0 - deg)
         side0 = np.flatnonzero(in0)
         side1 = np.flatnonzero(~in0)
@@ -355,7 +373,7 @@ def select_nodes(D: np.ndarray, count: int, seed: int | None = None,
     if allowed is not None and not lazy:
         raise ValueError("allowed= is for lazy D; restrict a dense D "
                          "with D[np.ix_(ids, ids)]")
-    jx = None if lazy else _jax_kernels()
+    jx = _jax_kernels("select_nodes", D=D)
     if jx is not None:
         return jx.select_nodes(D, count, seed=seed)
     n = D.shape[0]
@@ -419,23 +437,17 @@ def refine_batch(G_w: np.ndarray, D: np.ndarray, placements: np.ndarray,
                  ) -> np.ndarray:
     """Refine a (k, n) stack of candidate placements.
 
-    On the numpy backend this loops the module-global ``_pairwise_refine``
-    (so ``use_reference_impl`` still applies); on the jax backend the
-    whole stack refines in one jitted, vmapped device dispatch.
+    Where the jax backend serves the refine (:func:`_jax_kernels`), the
+    whole stack refines in one jitted, vmapped device dispatch; otherwise
+    this loops the module-global ``_pairwise_refine`` (the scalar
+    reference inside ``use_reference_impl``).
     """
     P = np.stack([np.asarray(p) for p in placements]) \
         if not isinstance(placements, np.ndarray) else placements
-    refiner = globals()["_pairwise_refine"]
-    # dispatch to the jitted batch only when the *vectorized* kernel is
-    # installed — under use_reference_impl the global is the scalar
-    # reference, which must run regardless of backend (compare against
-    # the saved original: the bare name would resolve to the same
-    # swapped global and never detect reference mode)
-    if refiner is _VECTORIZED_IMPL.get("_pairwise_refine"):
-        jx = _jax_kernels(G_w, D)
-        if jx is not None:
-            return jx.refine_many(G_w, D, P)
-    return np.stack([refiner(G_w, D, p) for p in P])
+    jx = _jax_kernels("refine", G_w, D)
+    if jx is not None:
+        return jx.refine_many(G_w, D, P)
+    return np.stack([_pairwise_refine(G_w, D, p) for p in P])
 
 
 def refine_subproblems(G_w: np.ndarray, D, groups,
@@ -445,20 +457,18 @@ def refine_subproblems(G_w: np.ndarray, D, groups,
     ``_pairwise_refine(G_w[np.ix_(F, F)], D, placements[s])`` would
     refine it alone.
 
-    When the vectorized kernel is installed and the jax backend serves
-    the guest and ``D``, every sub-problem refines in one device
-    dispatch (:func:`repro.core.mapping_jax.refine_subproblems`, which
-    still asks ``refine_many`` for each sub-problem's result);
-    otherwise (numpy backend, ``use_reference_impl``, an asymmetric
-    guest, a straggling torus) this loops the module-global
-    ``_pairwise_refine`` over them in order.
+    Where the jax backend serves the refine (:func:`_jax_kernels`),
+    every sub-problem refines in one device dispatch
+    (:func:`repro.core.mapping_jax.refine_subproblems`, which still asks
+    ``refine_many`` for each sub-problem's result); otherwise (numpy
+    backend, ``use_reference_impl``, an asymmetric guest, a straggling
+    torus) this loops the module-global ``_pairwise_refine`` over them in
+    order.
     """
-    refiner = globals()["_pairwise_refine"]
-    if refiner is _VECTORIZED_IMPL.get("_pairwise_refine"):
-        jx = _jax_kernels(G_w, D)
-        if jx is not None:
-            return jx.refine_subproblems(G_w, D, groups, placements)
-    return [refiner(G_w[np.ix_(F, F)], D, p)
+    jx = _jax_kernels("refine", G_w, D)
+    if jx is not None:
+        return jx.refine_subproblems(G_w, D, groups, placements)
+    return [_pairwise_refine(G_w[np.ix_(F, F)], D, p)
             for F, p in zip(groups, placements)]
 
 
@@ -492,8 +502,6 @@ def map_graph(
     coords: np.ndarray,
     D: np.ndarray | None = None,
     rng: np.random.Generator | None = None,
-    refine: bool = True,
-    portfolio: bool = True,
 ) -> np.ndarray:
     """ScotchMap analogue: map processes {0..n-1} onto ``nodes``.
 
@@ -511,13 +519,10 @@ def map_graph(
 
     Returns placement: array of node ids, one per process.
     """
-    candidates = _map_candidates(G_w, np.asarray(nodes), coords, D, rng,
-                                 portfolio=portfolio)
+    candidates = _map_candidates(G_w, np.asarray(nodes), coords, D, rng)
     if D is None:
         return candidates[0]
-    stack = np.stack(candidates)
-    if refine:
-        stack = refine_batch(G_w, D, stack)
+    stack = refine_batch(G_w, D, np.stack(candidates))
     scores = hop_bytes_batch(G_w, D, stack)
     return stack[int(np.argmin(scores))]
 
@@ -528,13 +533,11 @@ def _map_candidates(
     coords: np.ndarray,
     D: np.ndarray | None,
     rng: np.random.Generator | None,
-    portfolio: bool = True,
 ) -> list[np.ndarray]:
     """Unrefined candidate placements of one (guest, node set) mapping:
-    dual recursive bipartitioning, plus (with ``D`` and ``portfolio``)
-    the sequential snake seed.  Shared by :func:`map_graph` and
-    :func:`best_map` so multi-set searches can refine every candidate in
-    one batch."""
+    dual recursive bipartitioning, plus (with ``D``) the sequential snake
+    seed.  Shared by :func:`map_graph` and :func:`best_map` so multi-set
+    searches can refine every candidate in one batch."""
     n = G_w.shape[0]
     nodes = np.asarray(nodes)
     assert len(nodes) >= n, "not enough nodes"
@@ -563,17 +566,13 @@ def _map_candidates(
 
     if D is None:
         return [placement]
-    candidates = [placement]
-    if portfolio:
-        # sequential seed: process i -> i-th node along a snake curve of the
-        # available nodes (near-optimal chain for banded guests)
-        candidates.append(snake_order(nodes, coords)[:n].copy())
-    return candidates
+    # sequential seed: process i -> i-th node along a snake curve of the
+    # available nodes (near-optimal chain for banded guests)
+    return [placement, snake_order(nodes, coords)[:n].copy()]
 
 
 def _pairwise_refine(
     G_w: np.ndarray, D: np.ndarray, placement: np.ndarray,
-    max_passes: int = 3, movers: int = 64, extra_passes: int = 13,
 ) -> np.ndarray:
     """Greedy pairwise-swap refinement of a full placement under hop-bytes.
 
@@ -593,11 +592,12 @@ def _pairwise_refine(
     incrementally in O(n) — two row/column gathers — instead of
     recomputing the O(n^2) gather per pass.
 
-    Passes beyond ``max_passes`` (up to ``extra_passes`` more) continue only
-    while improving: they are nearly free at array speed and let the refiner
-    descend at least as far as the scalar reference, which stops after
-    ``max_passes`` regardless.  A pass that accepts no swap leaves all state
-    unchanged, so the first such pass terminates refinement.
+    Each pass tries the ``REFINE_MOVERS`` worst processes as movers.  Up
+    to ``REFINE_PASSES`` passes run while they improve: they are nearly
+    free at array speed and let the refiner descend at least as far as
+    the scalar reference, which stops after its own pass count regardless.
+    A pass that accepts no swap leaves all state unchanged, so the first
+    such pass terminates refinement.
 
     Mover order uses a *stable* descending sort so the swap sequence is a
     deterministic function of the inputs — the contract the jax backend's
@@ -607,10 +607,9 @@ def _pairwise_refine(
     n = len(p)
     if n <= 1:
         return p
-    jx = _jax_kernels(G_w, D)
+    jx = _jax_kernels("refine", G_w, D)
     if jx is not None:
-        return jx.pairwise_refine(G_w, D, p, max_passes=max_passes,
-                                  movers=movers, extra_passes=extra_passes)
+        return jx.refine_many(G_w, D, p[None])[0]
     G = G_w
     if np.count_nonzero(np.diagonal(G)):
         G = G.copy()
@@ -626,18 +625,18 @@ def _pairwise_refine(
     def gathered_row(node: int) -> np.ndarray:
         return 0.5 * (D[node, p] + D[p, node])
 
-    for _ in range(max_passes + extra_passes):
+    for _ in range(REFINE_PASSES):
         improved = False
         # worst offenders first; stable descending (ties keep index order)
         # so the swap sequence is deterministic and exactly replicable by
         # the jax port
-        order = np.argsort(-contrib, kind="stable")[: min(n, movers)]
+        order = np.argsort(-contrib, kind="stable")[:REFINE_MOVERS]
         for i in order:
             gains = (contrib[i] + contrib - 2.0 * C[i]
                      - M @ G[i] - G @ M[i])
             gains[i] = 0.0
             j = int(np.argmax(gains))
-            if gains[j] <= 1e-9:
+            if gains[j] <= GAIN_EPS:
                 continue
             # accept swap (i, j); update all state in O(n)
             p[i], p[j] = p[j], p[i]
@@ -680,7 +679,7 @@ def _pairwise_refine_reference(
         contrib = (G_w * Dp).sum(axis=1)
         # worst offenders, stable descending — same deterministic mover
         # order as the vectorized kernel so the comparison stays paired
-        order = np.argsort(-contrib, kind="stable")[: min(n, 64)]
+        order = np.argsort(-contrib, kind="stable")[:REFINE_MOVERS]
         for i in order:
             best_d, best_j = 0.0, -1
             mask = np.ones(n, dtype=bool)
@@ -699,7 +698,7 @@ def _pairwise_refine_reference(
                     + G_w[i, j] * D[p[i], p[j]]
                 mask[j] = True
                 d = old - new
-                if d > best_d + 1e-9:
+                if d > best_d + GAIN_EPS:
                     best_d, best_j = d, j
             if best_j >= 0:
                 p[i], p[best_j] = p[best_j], p[i]
@@ -726,8 +725,9 @@ def use_reference_impl():
     measures speedups against and differential tests compare quality with.
     """
     g = globals()
-    saved = {name: g[name] for name in _VECTORIZED_IMPL}
-    g.update({name: g[name + "_reference"] for name in _VECTORIZED_IMPL})
+    saved = {name: g[name] for name in (*_VECTORIZED_IMPL, "_reference_mode")}
+    g.update({name: g[name + "_reference"] for name in _VECTORIZED_IMPL},
+             _reference_mode=True)
     try:
         yield
     finally:
@@ -764,7 +764,7 @@ def greedy_placement(
     Pair order is a stable descending sort (ties keep upper-triangle
     order), the deterministic contract shared with the jax port.
     """
-    jx = None if is_lazy(D) else _jax_kernels()
+    jx = _jax_kernels("greedy_placement", D=D)
     if jx is not None:
         return jx.greedy_placement(G_w, nodes, D)
     n = G_w.shape[0]

@@ -63,11 +63,11 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro.kernels.swap_gain.ref import GAIN_EPS
+
 from . import backend as _backend
 from . import spans as _spans
-
-# swap acceptance threshold — identical to the NumPy kernel
-_GAIN_EPS = 1e-9
+from .mapping import REFINE_MOVERS, REFINE_PASSES
 
 
 # --------------------------------------------------------------------------
@@ -223,22 +223,31 @@ def _csr_rows(lane, row, col, val, S: int, n_rows: int):
 
     Rows are padded with (index 0, weight 0.0) entries — gathers against
     them multiply by exactly 0.0, so padding never changes a result — to
-    one width ``k``: the widest row rounded up to a multiple of 4 (tight
-    enough that padded gathers stay cheap, coarse enough that
-    compile-cache keys rarely vary), at most the power of two of
-    ``n_rows``."""
+    one width ``k`` (:func:`_row_layout`)."""
     order = np.lexsort((col, row, lane))
     lane, row, col, val = lane[order], row[order], col[order], val[order]
     slot = lane * n_rows + row
     nnz = np.bincount(slot, minlength=S * n_rows)
     pos = np.arange(len(slot)) - (np.cumsum(nnz) - nnz)[slot]
-    k_true = max(1, int(nnz.max(initial=0)))
-    k = min(_pow2(n_rows), (k_true + 3) & ~3)
+    k = int(_row_layout(nnz, _pow2(n_rows))[0])
     idx = np.zeros((S, n_rows, k), dtype=np.int32)
     vals = np.zeros((S, n_rows, k), dtype=np.float64)
     idx[lane, row, pos] = col
     vals[lane, row, pos] = val
     return idx, vals
+
+
+def _row_layout(nnz, n_pad):
+    """``(k, dense)`` of a guest whose rows hold ``nnz`` nonzeros (last
+    axis; leading axes are separate guests, each padded to ``n_pad``
+    rows): the CSR row width ``k`` — the widest row rounded up to a
+    multiple of 4 (tight enough that padded gathers stay cheap, coarse
+    enough that compile-cache keys rarely vary), at most ``n_pad`` — and
+    whether the refine takes the dense ``swap_select`` branch instead:
+    rows over half full and wider than 8."""
+    widest = np.maximum(1, np.max(nnz, axis=-1, initial=0))
+    k = np.minimum(n_pad, (widest + 3) & ~3)
+    return k, k > np.maximum(8, n_pad // 2)
 
 
 def _sym_host(D: np.ndarray) -> np.ndarray:
@@ -323,7 +332,7 @@ def _refine_one(p0, idx, val, G_dense, Ds, n_valid, *, movers: int,
         g = jnp.where(valid, g, -jnp.inf)
         j_raw = jnp.argmax(g)
         gain = g[j_raw]
-        j = jnp.where((gain > _GAIN_EPS) & (i < n_valid), j_raw, i)
+        j = jnp.where((gain > GAIN_EPS) & (i < n_valid), j_raw, i)
         return gain, j.astype(jnp.int32)
 
     def sparse_col(i):
@@ -338,7 +347,7 @@ def _refine_one(p0, idx, val, G_dense, Ds, n_valid, *, movers: int,
         # values, so no O(n^2) masked select of M is ever needed and XLA
         # keeps the loop-carried matrix in place.
         gain, j = select_mover(M, contrib, i)
-        do = (i < n_valid) & (gain > _GAIN_EPS)
+        do = (i < n_valid) & (gain > GAIN_EPS)
 
         oi, oj = p[i], p[j]
         p_old = p
@@ -513,8 +522,7 @@ def _device_distances(D, be):
 
 
 def refine_many(G_w: np.ndarray, D: np.ndarray, placements: np.ndarray,
-                max_passes: int = 3, movers: int = 64,
-                extra_passes: int = 13, *, lane=None) -> np.ndarray:
+                *, lane=None) -> np.ndarray:
     """Batched ``_pairwise_refine``: (B, n) placements in one dispatch.
 
     With multiple visible devices (``backend.JaxBackend.device_count``
@@ -544,9 +552,7 @@ def refine_many(G_w: np.ndarray, D: np.ndarray, placements: np.ndarray,
     B, n = np.atleast_2d(placements).shape
     with _spans.span("refine", B=B, n=n):
         with be.scope(), _spans.span("refine.prepare"):
-            run, args, n_dev = refine_program(G_w, D, placements,
-                                              max_passes, movers,
-                                              extra_passes)
+            run, args, n_dev = refine_program(G_w, D, placements)
             if n_dev > 1:
                 be.stats["sharded_dispatches"] += 1
             out = run(*args)
@@ -555,9 +561,7 @@ def refine_many(G_w: np.ndarray, D: np.ndarray, placements: np.ndarray,
     return out if np.asarray(placements).ndim == 2 else out[0]
 
 
-def refine_program(G_w: np.ndarray, D, placements: np.ndarray,
-                   max_passes: int = 3, movers: int = 64,
-                   extra_passes: int = 13):
+def refine_program(G_w: np.ndarray, D, placements: np.ndarray):
     """``(jitted refine, operands, n_dev)``: the dispatch
     :func:`refine_many` makes for these arguments, on ``n_dev`` devices.
     ``run.lower(*operands)`` shows the program it compiles.  Call inside
@@ -566,19 +570,18 @@ def refine_program(G_w: np.ndarray, D, placements: np.ndarray,
     P, n, n_pad = _pad_placements(np.atleast_2d(placements))
     idx, val, G_dense, dense = _guest_device(G_w, n_pad, be)
     Ds, dims, scale = _device_distances(D, be)
-    movers_eff = min(movers, n_pad)
+    movers = min(REFINE_MOVERS, n_pad)
     B = P.shape[0]
     n_dev = min(int(be.device_count), B)
     if n_dev > 1:
         pad_b = (-B) % n_dev
         if pad_b:
             P = np.pad(P, ((0, pad_b), (0, 0)), mode="edge")
-        run = _refine_jit_sharded(movers_eff, max_passes + extra_passes,
-                                  dense, dims, scale, n_dev)
+        run = _refine_jit_sharded(movers, REFINE_PASSES, dense, dims,
+                                  scale, n_dev)
         args = _shard_args(n_dev, P, idx, val, G_dense, Ds, jnp.int32(n))
     else:
-        run = _refine_jit(movers_eff, max_passes + extra_passes, dense,
-                          dims, scale)
+        run = _refine_jit(movers, REFINE_PASSES, dense, dims, scale)
         args = (jnp.asarray(P), idx, val, G_dense, Ds, jnp.int32(n))
     return run, args, n_dev
 
@@ -588,12 +591,12 @@ def _guest_device(G_w: np.ndarray, n_pad: int, be):
     cached by guest identity so repeated refine/score calls against one
     job's graph pay a single transfer."""
     def build():
-        idx, val, k, _G = _sparse_rows(G_w)
+        idx, val, _, _G = _sparse_rows(G_w)
         n = idx.shape[0]
         if n_pad != n:
             idx = np.pad(idx, ((0, n_pad - n), (0, 0)))
             val = np.pad(val, ((0, n_pad - n), (0, 0)))
-        dense = k > max(8, n_pad // 2)
+        _, dense = _row_layout(np.count_nonzero(_G, axis=1), n_pad)
         fdt = be.np_dtype
         Gd = _G
         if dense and n_pad != n:
@@ -608,15 +611,6 @@ def _guest_device(G_w: np.ndarray, n_pad: int, be):
     if sub not in cache:
         cache[sub] = build()
     return cache[sub]
-
-
-def pairwise_refine(G_w: np.ndarray, D: np.ndarray, placement: np.ndarray,
-                    max_passes: int = 3, movers: int = 64,
-                    extra_passes: int = 13) -> np.ndarray:
-    """Drop-in for :func:`repro.core.mapping._pairwise_refine`."""
-    return refine_many(G_w, D, np.asarray(placement)[None, :],
-                       max_passes=max_passes, movers=movers,
-                       extra_passes=extra_passes)[0]
 
 
 # --------------------------------------------------------------------------
@@ -671,14 +665,11 @@ class SubproblemBatch:
     sub-problem takes its own :func:`refine_many` dispatch instead.
     """
 
-    def __init__(self, G_w: np.ndarray, D, groups, placements,
-                 max_passes: int = 3, movers: int = 64,
-                 extra_passes: int = 13):
+    def __init__(self, G_w: np.ndarray, D, groups, placements):
         self.D = D
         self.inputs = [np.asarray(p, dtype=np.int64) for p in placements]
         self.sizes = np.array([len(F) for F in groups], dtype=np.int64)
         self.n_pad = _pow2(int(self.sizes.max()))
-        self._passes = (max_passes, movers, extra_passes)
         S = len(groups)
         flat = np.concatenate(groups).astype(np.int64)
         lanes = np.repeat(np.arange(S), self.sizes)
@@ -694,14 +685,12 @@ class SubproblemBatch:
         keep = (lane_of[r] >= 0) & (lane_of[r] == lane_of[c])
         self._entries = (lane_of[r[keep]], loc[r[keep]], loc[c[keep]],
                          v[keep])
-        # the row width and dense test each sub-problem would get alone
+        # the dense test each sub-problem would get alone
         lane, row = self._entries[:2]
-        widest = np.bincount(lane * self.n_pad + row,
-                             minlength=S * self.n_pad).reshape(
-                                 S, self.n_pad).max(axis=1, initial=0)
+        nnz = np.bincount(lane * self.n_pad + row,
+                          minlength=S * self.n_pad).reshape(S, self.n_pad)
         own_pad = np.array([_pow2(n) for n in self.sizes])
-        k_own = np.minimum(own_pad, (np.maximum(1, widest) + 3) & ~3)
-        self.dense = bool((k_own > np.maximum(8, own_pad // 2)).any())
+        self.dense = bool(_row_layout(nnz, own_pad)[1].any())
         self._out = None
 
     def program(self):
@@ -724,9 +713,8 @@ class SubproblemBatch:
                 np.pad(a, ((0, S_pad - S),) + ((0, 0),) * (a.ndim - 1),
                        mode="edge") for a in (P, idx, val, n_valid))
         Ds, dims, scale = _device_distances(self.D, be)
-        max_passes, movers, extra_passes = self._passes
-        run = _refine_jit_subproblems(min(movers, n_pad),
-                                      max_passes + extra_passes, dims, scale)
+        run = _refine_jit_subproblems(min(REFINE_MOVERS, n_pad),
+                                      REFINE_PASSES, dims, scale)
         P, idx, val, n_valid = jax.device_put(
             (P, idx, val.astype(be.np_dtype), n_valid))
         return run, (P, idx, val, jnp.zeros((1, 1), be.np_dtype), Ds,
@@ -749,9 +737,8 @@ class SubproblemBatch:
         return self._out[s, :self.sizes[s]].copy()
 
 
-def refine_subproblems(G_w: np.ndarray, D, groups, placements,
-                       max_passes: int = 3, movers: int = 64,
-                       extra_passes: int = 13) -> list[np.ndarray]:
+def refine_subproblems(G_w: np.ndarray, D, groups,
+                       placements) -> list[np.ndarray]:
     """Refine disjoint sub-problems of one guest, all in one dispatch.
 
     Each sub-problem goes through :func:`refine_many` on its own guest
@@ -764,10 +751,8 @@ def refine_subproblems(G_w: np.ndarray, D, groups, placements,
     """
     if not len(groups):
         return []
-    batch = SubproblemBatch(G_w, D, groups, placements, max_passes, movers,
-                            extra_passes)
-    return [refine_many(G_w[np.ix_(F, F)], D, p[None], max_passes, movers,
-                        extra_passes,
+    batch = SubproblemBatch(G_w, D, groups, placements)
+    return [refine_many(G_w[np.ix_(F, F)], D, p[None],
                         lane=None if batch.dense else (batch, s))[0]
             for s, (F, p) in enumerate(zip(groups, batch.inputs))]
 
@@ -802,10 +787,6 @@ def hop_bytes_batch(G_w: np.ndarray, D: np.ndarray,
         out = _hop_bytes_jit(dims, scale)(
             jnp.asarray(P), idx, val, Ds, jnp.int32(n))
     return np.asarray(out, dtype=np.float64)
-
-
-def hop_bytes(G_w: np.ndarray, D: np.ndarray, placement: np.ndarray) -> float:
-    return float(hop_bytes_batch(G_w, D, np.asarray(placement)[None, :])[0])
 
 
 # --------------------------------------------------------------------------

@@ -167,8 +167,7 @@ _LOCAL_REFINE_MAX = 1024
 
 def multilevel_map(G_w: np.ndarray, nodes: np.ndarray, coords: np.ndarray,
                    D=None, rng: np.random.Generator | None = None,
-                   coarse_target: int = 160,
-                   refine: bool = True) -> np.ndarray:
+                   coarse_target: int = 160) -> np.ndarray:
     """Multilevel analogue of :func:`mapping.map_graph`.
 
     Coarsening a guest already at/below ``coarse_target`` is a no-op, and
@@ -184,8 +183,7 @@ def multilevel_map(G_w: np.ndarray, nodes: np.ndarray, coords: np.ndarray,
 
     levels, Gc, sizes_c = coarsen(G_w, coarse_target)
     if not levels:
-        return mapping.map_graph(G_w, nodes, coords, D=D, rng=rng,
-                                 refine=refine)
+        return mapping.map_graph(G_w, nodes, coords, D=D, rng=rng)
 
     placement = np.full(n, -1, dtype=np.int64)
     # each level's refine sub-problems (process sets), recorded by the
@@ -212,7 +210,7 @@ def multilevel_map(G_w: np.ndarray, nodes: np.ndarray, coords: np.ndarray,
         # ``members`` occupy ``chunk``; their arrangement is polished,
         # after the descent, against the *global* metric restricted to
         # this subproblem
-        if refine and D is not None:
+        if D is not None:
             procs = _procs_by_level[li - 1]
             F = np.concatenate([procs[int(m)] for m in members]) \
                 if len(members) > 1 else procs[int(members[0])]
@@ -253,10 +251,8 @@ def multilevel_map(G_w: np.ndarray, nodes: np.ndarray, coords: np.ndarray,
     # final global polish + snake portfolio — same candidate contract as
     # the flat mapper, so multilevel can never lose to the sequential
     # seed it would otherwise have skipped
-    candidates = np.stack([placement,
-                           mapping.snake_order(nodes, coords)[:n]])
-    if refine:
-        candidates = mapping.refine_batch(G_w, D, candidates)
+    candidates = mapping.refine_batch(G_w, D, np.stack(
+        [placement, mapping.snake_order(nodes, coords)[:n]]))
     scores = mapping.hop_bytes_batch(G_w, D, candidates)
     return candidates[int(np.argmin(scores))]
 

@@ -23,8 +23,9 @@ def swap_gain_ref(M, G, contrib, i):
             - M @ Gi - G @ Mi)
 
 
-# swap acceptance threshold — shared with the refine loops
-# (repro.core.mapping._pairwise_refine and mapping_jax._refine_one)
+# swap acceptance threshold of the device refine: read here, by the
+# Pallas kernel and by repro.core.mapping_jax._refine_one; the NumPy
+# loops read repro.core.mapping.GAIN_EPS, which holds the same value
 GAIN_EPS = 1e-9
 
 

@@ -148,6 +148,17 @@ def test_use_reference_impl_restores():
         assert getattr(mp, name) is fn
 
 
+def test_swap_threshold_matches_the_device_refine():
+    """The NumPy refine loops and the device refine accept a swap at the
+    same gain: ``mapping.GAIN_EPS`` is the value of
+    ``kernels.swap_gain.ref.GAIN_EPS``, the device refine's definition
+    (a NumPy-only install cannot import it, so both are kept)."""
+    pytest.importorskip("jax")
+    from repro.kernels.swap_gain import ref
+
+    assert mp.GAIN_EPS == ref.GAIN_EPS
+
+
 def test_hop_bytes_batch_matches_scalar():
     rng = np.random.default_rng(0)
     topo = TorusTopology((4, 4))
